@@ -2,6 +2,9 @@
 
   python -m benchmarks.run            # small defaults (CI-sized)
   python -m benchmarks.run --full     # paper-shaped sweeps (slow on 1 core)
+
+This process never starts JAX: each benchmark runs its JAX phases in
+processes of their own, since a chip belongs to one process at a time.
 """
 from __future__ import annotations
 
@@ -16,6 +19,8 @@ def main(argv=None):
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--outdir", default="experiments/bench")
     args = ap.parse_args(argv)
+    from repro import compile_cache
+    compile_cache.enable()
     os.makedirs(args.outdir, exist_ok=True)
 
     print("== runtime micro-overheads (paper §V overhead discussion) ==")

@@ -10,6 +10,10 @@ causes).  The ``seq-baseline`` row serves the identical schedule one
 request at a time — same jitted steps, same greedy argmax, so the
 delta is pure continuous-batching + prefill/decode overlap.
 
+Each JAX phase on this host (the baseline and every in-proc row) runs in
+a process of its own, and this process never starts JAX: a chip belongs
+to one process at a time, and the socket rows' server process needs it.
+
 ``--insights`` runs :func:`repro.insights.analyze` over each event-driven
 row's ``Session.stats()`` and prints the findings — under an offered
 rate the slots cannot sustain, the ``admission-backpressure`` rule fires
@@ -21,7 +25,9 @@ import argparse
 import json
 import os
 
+from repro.net.launch import run_in_process
 from repro.serve import LoadSpec, all_requests, run_sequential, run_serve
+from repro.serve.loadgen import summarize
 
 
 def _print_row(row):
@@ -33,6 +39,12 @@ def _print_row(row):
           f"tok p50={row['per_token_p50_ms']:5.2f}ms")
 
 
+def _sequential_summary(cfg, reqs, max_len: int, seed: int):
+    recs = run_sequential(cfg, reqs, max_len=max_len, seed=seed)
+    span = max(x["t_done"] for x in recs) - min(x["t_sched"] for x in recs)
+    return summarize(recs, span)
+
+
 def run(rps=(4.0, 16.0), requests: int = 24, clients: int = 2,
         slots: int = 4, max_len: int = 64,
         transports=("inproc", "socket"), procs: int = 2,
@@ -41,7 +53,6 @@ def run(rps=(4.0, 16.0), requests: int = 24, clients: int = 2,
     """One result row per (impl, rps); all rows share the arrival
     schedule at a given rps, so columns are directly comparable."""
     from repro.configs import ARCHS, reduce_cfg
-    from repro.serve.loadgen import summarize
 
     cfg = reduce_cfg(ARCHS[arch].cfg)
     rows = []
@@ -49,20 +60,19 @@ def run(rps=(4.0, 16.0), requests: int = 24, clients: int = 2,
     for r in rps:
         load = LoadSpec(rps=float(r), requests=requests, seed=seed)
         reqs = all_requests(load, clients, cfg.vocab)
-        span_reqs = run_sequential(cfg, reqs, max_len=max_len, seed=seed)
-        span = (max(x["t_done"] for x in span_reqs)
-                - min(x["t_sched"] for x in span_reqs))
         row = {"impl": "seq-baseline", "rps": float(r),
                "transport": "-", "slots": 1,
-               **summarize(span_reqs, span)}
+               **run_in_process(_sequential_summary, cfg, reqs, max_len,
+                                seed)}
         rows.append(row)
         _print_row(row)
         for tr in transports:
-            res = run_serve(arch=arch, clients=clients, slots=slots,
-                            max_len=max_len, load=load,
-                            queue_bound=queue_bound, transport=tr,
-                            procs=procs if tr == "socket" else None,
-                            seed=seed)
+            kw = dict(arch=arch, clients=clients, slots=slots,
+                      max_len=max_len, load=load, queue_bound=queue_bound,
+                      transport=tr, seed=seed)
+            # a socket round spawns its own rank processes
+            res = (run_serve(procs=procs, **kw) if tr == "socket"
+                   else run_in_process(run_serve, **kw))
             row = {"impl": f"edat-{tr}", "rps": float(r),
                    "transport": tr, "slots": slots,
                    **res["summary"],
@@ -92,6 +102,8 @@ def run(rps=(4.0, 16.0), requests: int = 24, clients: int = 2,
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("out", nargs="?", default=None,
                     help="optional path for the bench JSON")

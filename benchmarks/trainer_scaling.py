@@ -14,6 +14,10 @@ over the wire.  Each row records:
   recorded step) — the trainer-level event rate the coalescing fast
   path feeds;
 * ``loss_first``/``loss_last`` — sanity that the thing actually trains.
+
+In-proc rows run in a process of their own and this process never
+starts JAX: a chip belongs to one process at a time, and the socket
+rows' rank processes need it.
 """
 from __future__ import annotations
 
@@ -23,6 +27,8 @@ import os
 import time
 
 import numpy as np
+
+from repro.net.launch import run_in_process
 
 
 def _row_from_history(history, steps, wall, label, ranks, procs):
@@ -40,6 +46,20 @@ def _row_from_history(history, steps, wall, label, ranks, procs):
           f"grad-ev/s={row['grad_events_per_s']:8.1f} "
           f"loss {loss_first:.3f}->{loss_last:.3f}")
     return row
+
+
+def _inproc_run(n_ranks: int, steps: int):
+    """One in-proc (threads-as-ranks) round: ``(history, wall seconds)``."""
+    from repro.models import build_model
+    from repro.runtime_dist import EventDrivenTrainer
+    from repro.runtime_dist.trainer import _demo_cfgs
+    model_cfg, data_cfg, opt_cfg, trainer_cfg = _demo_cfgs(
+        n_ranks, steps, ckpt_dir=None)
+    tr = EventDrivenTrainer(build_model(model_cfg), data_cfg, opt_cfg,
+                            trainer_cfg)
+    t0 = time.monotonic()
+    out = tr.run(timeout=600.0)
+    return out["history"], time.monotonic() - t0
 
 
 def run(steps: int = 12, ranks=(1, 2, 4), transport: str = "inproc",
@@ -66,14 +86,8 @@ def run(steps: int = 12, ranks=(1, 2, 4), transport: str = "inproc",
             rows.append(_row_from_history(res["history"], steps, wall,
                                           "edat-socket", nr, np_))
         else:
-            from repro.models import build_model
-            from repro.runtime_dist import EventDrivenTrainer
-            tr = EventDrivenTrainer(build_model(model_cfg), data_cfg,
-                                    opt_cfg, trainer_cfg)
-            t0 = time.monotonic()
-            out_run = tr.run(timeout=600.0)
-            wall = time.monotonic() - t0
-            rows.append(_row_from_history(out_run["history"], steps, wall,
+            history, wall = run_in_process(_inproc_run, nr, steps)
+            rows.append(_row_from_history(history, steps, wall,
                                           "edat-inproc", nr, 1))
     result = {"steps": steps, "transport": transport, "rows": rows}
     if out:
@@ -84,6 +98,8 @@ def run(steps: int = 12, ranks=(1, 2, 4), transport: str = "inproc",
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("out", nargs="?", default=None,
                     help="optional path for the bench JSON")

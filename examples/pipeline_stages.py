@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import edat
+from repro import compile_cache, edat
 
 
 def make_stage_fns(d, layers_per_stage, last):
@@ -43,6 +43,7 @@ def make_stage_fns(d, layers_per_stage, last):
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--stages", type=int, default=3)
     ap.add_argument("--microbatches", type=int, default=8)

@@ -13,11 +13,13 @@ event-carried backpressure when the admission queue outgrows its bound.
 """
 import argparse
 
+from repro import compile_cache
 from repro.configs import ARCHS
 from repro.serve import DEFAULT_MAX_LEN, LoadSpec, run_serve
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma3-1b", choices=sorted(ARCHS))
     ap.add_argument("--clients", type=int, default=2)

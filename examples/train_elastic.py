@@ -18,11 +18,12 @@ import os
 import tempfile
 import time
 
-from repro import edat
+from repro import compile_cache, edat
 from repro.runtime_dist.trainer import _demo_cfgs, trainer_program
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--transport", choices=("inproc", "socket"),
                     default="inproc")

@@ -17,6 +17,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import ARCHS, reduce_cfg
 from repro.data import DataCfg
 from repro.models import build_model
@@ -36,6 +37,7 @@ def preset_cfg(arch: str, preset: str):
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma2-2b", choices=sorted(ARCHS))
     ap.add_argument("--preset", default="small", choices=["small", "100m"])

@@ -17,8 +17,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels._compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -102,7 +101,6 @@ def flash_attention_fwd(q, k, v, *, scale: float, causal: bool = True,
         _fa_kernel, scale=scale, causal=causal, window=window,
         softcap=softcap, block_q=block_q, block_k=block_k, num_kv_blocks=nk)
 
-    from jax.experimental.pallas import tpu as pltpu
     return pl.pallas_call(
         kernel,
         grid=(B, H, nq, nk),
@@ -122,8 +120,7 @@ def flash_attention_fwd(q, k, v, *, scale: float, causal: bool = True,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
-            pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -13,10 +13,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
+
 from . import kernel as _k
 from . import ref as _ref
-
-_INTERPRET = jax.default_backend() == "cpu"
 
 
 def supported(q, k, window, softcap) -> bool:
@@ -36,7 +36,7 @@ def _fa(q, k, v, scale, causal, window, softcap):
     vt = jnp.swapaxes(v, 1, 2)
     out = _k.flash_attention_fwd(qt, kt, vt, scale=scale, causal=causal,
                                  window=window, softcap=softcap,
-                                 interpret=_INTERPRET)
+                                 interpret=interpret_mode())
     return jnp.swapaxes(out, 1, 2)
 
 

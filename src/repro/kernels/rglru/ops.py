@@ -6,10 +6,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
+
 from . import kernel as _k
 from .ref import rglru_ref
-
-_INTERPRET = jax.default_backend() == "cpu"
 
 
 def supported(T, W) -> bool:
@@ -18,7 +18,7 @@ def supported(T, W) -> bool:
 
 @jax.custom_vjp
 def _rglru(x, r, i, lam):
-    return _k.rglru_fwd(x, r, i, lam, interpret=_INTERPRET)
+    return _k.rglru_fwd(x, r, i, lam, interpret=interpret_mode())
 
 
 def _fwd(x, r, i, lam):

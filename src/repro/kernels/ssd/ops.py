@@ -6,10 +6,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
+
 from . import kernel as _k
 from .ref import ssd_reference
-
-_INTERPRET = jax.default_backend() == "cpu"
 
 
 def supported(T, chunk, Pd, N) -> bool:
@@ -18,7 +18,16 @@ def supported(T, chunk, Pd, N) -> bool:
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def _ssd(x, dt, a_log, b, c, chunk):
-    return _k.ssd_fwd(x, dt, a_log, b, c, chunk=chunk, interpret=_INTERPRET)
+    # model layout (B, T, H, .) -> kernel layout (B, H, T, .)
+    B, T, H, _ = x.shape
+    dt = jnp.swapaxes(dt, 1, 2)                                # (B, H, T)
+    da = dt * -jnp.exp(a_log.astype(jnp.float32))[:, None]
+    cum = jnp.cumsum(da.reshape(B, H, T // chunk, chunk), axis=-1)
+    y = _k.ssd_fwd(jnp.swapaxes(x, 1, 2), dt.reshape(B, H, 1, T),
+                   cum.reshape(B, H, 1, T),
+                   jnp.swapaxes(b, 1, 2), jnp.swapaxes(c, 1, 2),
+                   chunk=chunk, interpret=interpret_mode())
+    return jnp.swapaxes(y, 1, 2)
 
 
 def _fwd(x, dt, a_log, b, c, chunk):
@@ -41,12 +50,6 @@ _ssd.defvjp(_fwd, _bwd)
 def ssd(x, dt, a_log, b, c, *, chunk: int = 128):
     """x: (B,T,H,P); dt: (B,T,H); a_log: (H,); b,c: (B,T,G,N).
 
-    Broadcasts groups to heads then runs the kernel."""
-    H = x.shape[2]
-    G = b.shape[2]
-    if G != H:
-        rep = H // G
-        b = jnp.repeat(b, rep, axis=2)
-        c = jnp.repeat(c, rep, axis=2)
+    Head h reads group h // (H // G) inside the kernel (no broadcast)."""
     out = _ssd(x, dt.astype(jnp.float32), a_log, b, c, chunk)
     return out.astype(jnp.float32)

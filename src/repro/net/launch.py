@@ -36,10 +36,16 @@ spawns, and reaps.
 Every child also exports ``EDAT_RANK`` / ``EDAT_LOCAL_RANKS`` /
 ``EDAT_NRANKS`` / ``EDAT_COORD`` so user code can introspect its
 placement.
+
+On a host with TPU chips, a chip belongs to one process at a time, so
+each spawned process is pinned to its own chip (placement entry ``p`` to
+chip ``p % n_chips``) through libtpu's per-process visibility
+environment; the launching parent must stay off JAX while they run.
 """
 from __future__ import annotations
 
 import argparse
+import glob
 import importlib
 import importlib.util
 import multiprocessing as mp
@@ -47,6 +53,7 @@ import os
 import socket
 import sys
 import time
+import traceback
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 MainSpec = Union[Callable, str]
@@ -61,6 +68,14 @@ def _free_port(host: str = "127.0.0.1") -> int:
         s.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
         s.bind((host, 0))
         return s.getsockname()[1]
+
+
+def tpu_chip_count() -> int:
+    """TPU chips on this host, counted from their device files: nothing
+    here may start JAX, since the launching parent must not hold a chip
+    its children need."""
+    return (len(glob.glob("/dev/accel[0-9]*"))
+            or len(glob.glob("/dev/vfio/[0-9]*")))
 
 
 def default_placement(n_ranks: int, n_procs: int) -> List[Tuple[int, ...]]:
@@ -100,7 +115,10 @@ def _child_entry(ranks: Tuple[int, ...], n_ranks: int, coord_addr,
                  main: MainSpec, runtime_kwargs: Dict[str, Any],
                  run_timeout: float, net: Dict[str, Any], result_q,
                  launch_id: str = "", join: bool = False,
-                 ready_file: Optional[str] = None) -> None:
+                 ready_file: Optional[str] = None,
+                 env: Optional[Dict[str, str]] = None) -> None:
+    # before anything can start a JAX backend in this process
+    os.environ.update(env or {})
     os.environ["EDAT_RANK"] = str(ranks[0])
     os.environ["EDAT_LOCAL_RANKS"] = ",".join(str(r) for r in ranks)
     os.environ["EDAT_NRANKS"] = str(n_ranks)
@@ -229,6 +247,21 @@ class ProcessGroup:
                 return rs[0], rs
         raise KeyError(rank)
 
+    def _child_env(self, lead: int) -> Dict[str, str]:
+        """Per-process environment: on a TPU host, libtpu's visibility
+        variables that give the process one chip alone, as a one-chip
+        slice with a port of its own."""
+        n_chips = tpu_chip_count()
+        if not n_chips:
+            return {}
+        index = [rs[0] for rs in self.placement].index(lead)
+        port = _free_port(self._host)
+        return {"TPU_VISIBLE_CHIPS": str(index % n_chips),
+                "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+                "TPU_PROCESS_BOUNDS": "1,1,1",
+                "TPU_PROCESS_PORT": str(port),
+                "TPU_PROCESS_ADDRESSES": f"{self._host}:{port}"}
+
     def start(self) -> "ProcessGroup":
         import uuid
         ctx = mp.get_context("spawn")
@@ -240,7 +273,8 @@ class ProcessGroup:
                 target=_child_entry,
                 args=(rs, self.n_ranks, self._coord, self.main,
                       self.runtime_kwargs, self.run_timeout, self._net,
-                      self._q, self._launch_id),
+                      self._q, self._launch_id, False, None,
+                      self._child_env(rs[0])),
                 daemon=False,
                 name="edat-ranks" + "_".join(str(r) for r in rs))
             p.start()
@@ -287,7 +321,8 @@ class ProcessGroup:
             target=_child_entry,
             args=(rs, self.n_ranks, self._coord, self.main,
                   self.runtime_kwargs, self.run_timeout, self._net,
-                  self._q, self._launch_id, True, ready_file),
+                  self._q, self._launch_id, True, ready_file,
+                  self._child_env(lead)),
             daemon=False,
             name="edat-rejoin" + "_".join(str(r) for r in rs))
         p.start()
@@ -375,6 +410,44 @@ def launch_processes(n_ranks: int, main: MainSpec, *,
     pg = ProcessGroup(n_ranks, main, run_timeout=timeout, **kwargs)
     pg.start()
     return pg.wait(join_timeout, check=check)
+
+
+def _call_entry(conn, fn: Callable, args: tuple,
+                kwargs: Dict[str, Any]) -> None:
+    try:
+        conn.send(("ok", fn(*args, **kwargs)))
+    except BaseException:  # noqa: BLE001 - report, then non-zero exit
+        conn.send(("err", traceback.format_exc()))
+        raise SystemExit(1)
+    finally:
+        conn.close()
+
+
+def run_in_process(fn: Callable, *args: Any, **kwargs: Any) -> Any:
+    """``fn(*args, **kwargs)`` in a fresh spawned process; returns its
+    (picklable) result or raises ``RuntimeError`` with its traceback.
+
+    A process that starts JAX holds the host's chip until it exits, so a
+    benchmark runs each JAX phase in one of these and keeps the launching
+    parent off JAX, free to start socket rounds whose rank processes
+    need the chip.  ``fn`` must be importable (module level)."""
+    ctx = mp.get_context("spawn")
+    recv, send = ctx.Pipe(duplex=False)
+    p = ctx.Process(target=_call_entry, args=(send, fn, args, kwargs),
+                    daemon=False, name=f"edat-call-{fn.__name__}")
+    p.start()
+    send.close()
+    try:
+        kind, val = recv.recv()      # before join: a large result must drain
+    except EOFError:
+        kind, val = "err", "the process died without a result"
+    finally:
+        recv.close()
+        p.join()
+    if kind != "ok":
+        raise RuntimeError(f"{fn.__name__} failed in its own process "
+                           f"(exit code {p.exitcode}):\n{val}")
+    return val
 
 
 def _cli(argv=None) -> int:

@@ -2,6 +2,7 @@
 a small fake-device mesh in a subprocess (XLA device count must be set
 before jax initialises, hence the isolation)."""
 import json
+import os
 import subprocess
 import sys
 import textwrap
@@ -13,11 +14,13 @@ SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
     import json
     import jax
+    from jax.sharding import AxisType
     from repro.launch.cells import build_cell
     from repro.launch.hlo_analysis import analyze
 
     out = {}
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    mesh = jax.make_mesh((4, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     for arch, shape in [("gemma3-1b", "train_4k"),
                         ("granite-moe-1b-a400m", "decode_32k"),
                         ("whisper-tiny", "prefill_32k")]:
@@ -37,8 +40,8 @@ SCRIPT = textwrap.dedent("""
 def test_dryrun_cells_compile_on_mini_mesh():
     proc = subprocess.run([sys.executable, "-c", SCRIPT],
                           capture_output=True, text=True, timeout=1200,
-                          env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                               "HOME": "/root"})
+                          env={**os.environ, "PYTHONPATH": "src",
+                               "JAX_PLATFORMS": "cpu"})
     assert proc.returncode == 0, proc.stderr[-3000:]
     line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT:")]
     assert line, proc.stdout

@@ -172,3 +172,18 @@ def test_model_pallas_path_matches_ref_path():
     l_ref, _ = model_ref.loss(params, batch)
     l_pl, _ = model_pl.loss(params, batch)
     np.testing.assert_allclose(float(l_ref), float(l_pl), rtol=1e-4)
+
+
+# ------------------------------------------------- compiled vs interpreted
+@pytest.mark.parametrize("backend,interpret", [("tpu", False), ("cpu", True),
+                                               ("gpu", None)])
+def test_interpret_mode_follows_backend(monkeypatch, backend, interpret):
+    """Compiled on a TPU, interpreted on the CPU, refused elsewhere —
+    never a silent interpret-mode run on an accelerator."""
+    from repro import kernels
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(NotImplementedError, match="attn_impl='ref'"):
+            kernels.interpret_mode()
+    else:
+        assert kernels.interpret_mode() is interpret

@@ -295,3 +295,49 @@ def test_uneven_placement_spawned_ring():
                              n_procs=3, timeout=60)
     assert stats["events_sent"] == stats["events_received"] == 60
     assert stats["tasks_executed"] == 60
+
+
+# ------------------------------------------------- one chip per rank process
+def _record_chip_main(ctx, out_dir):
+    path = os.path.join(out_dir, f"rank{ctx.rank}")
+    with open(path, "w") as f:
+        f.write(os.environ.get("TPU_VISIBLE_CHIPS", "none"))
+
+
+def test_rank_processes_are_pinned_to_their_own_chip(monkeypatch, tmp_path):
+    """On a TPU host each spawned process gets libtpu's visibility
+    variables for chip ``p % n_chips`` before it runs any user code; on a
+    host without chips nothing is set."""
+    from repro.net import launch
+    pg = ProcessGroup(5, "unused:main", n_procs=3)     # (0,1) (2,3) (4,)
+    monkeypatch.setattr(launch, "tpu_chip_count", lambda: 0)
+    assert pg._child_env(0) == {}
+    monkeypatch.setattr(launch, "tpu_chip_count", lambda: 2)
+    envs = [pg._child_env(rs[0]) for rs in pg.placement]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "0"]
+    for e in envs:
+        assert e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+        assert e["TPU_PROCESS_BOUNDS"] == "1,1,1"
+        assert e["TPU_PROCESS_ADDRESSES"].endswith(":" + e["TPU_PROCESS_PORT"])
+    # and the spawned processes see it (JAX_PLATFORMS=cpu keeps them off
+    # any chip here)
+    launch_processes(4, functools.partial(_record_chip_main,
+                                          out_dir=str(tmp_path)),
+                     n_procs=2, timeout=60)
+    seen = {r: (tmp_path / f"rank{r}").read_text() for r in range(4)}
+    assert seen == {0: "0", 1: "0", 2: "1", 3: "1"}
+
+
+def _square(x):
+    return x * x
+
+
+def _boom():
+    raise ValueError("boom")
+
+
+def test_run_in_process_returns_result_and_reports_failure():
+    from repro.net.launch import run_in_process
+    assert run_in_process(_square, 7) == 49
+    with pytest.raises(RuntimeError, match="ValueError: boom"):
+        run_in_process(_boom)

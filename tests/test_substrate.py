@@ -1,5 +1,6 @@
 """Substrate layers: checkpoint store, synthetic data, optimizers,
 sharding rules, step builders."""
+import os
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -98,8 +99,13 @@ def test_adamw8_state_is_int8():
 
 
 # --------------------------------------------------------------- sharding
+def _mesh1():
+    return jax.make_mesh((1,), ("model",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
+
+
 def test_resolve_divisibility_fallback():
-    mesh = jax.make_mesh((1,), ("model",))  # single device: size-1 axes
+    mesh = _mesh1()  # single device: size-1 axes
     spec = resolve((8, 64), ("heads", "embed"), mesh, DEFAULT_RULES)
     assert spec == jax.sharding.PartitionSpec(None, None)
 
@@ -112,7 +118,7 @@ def test_resolve_conflict_drops_second():
         else None
     # build an abstract 4-way mesh via make_mesh if devices permit;
     # otherwise just exercise the code path with the host mesh
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = _mesh1()
     spec = resolve((16, 16), ("embed", "mlp"), mesh, rules)
     assert len(spec) == 2
 
@@ -120,7 +126,7 @@ def test_resolve_conflict_drops_second():
 @given(st.integers(1, 4), st.integers(1, 4))
 @settings(max_examples=10, deadline=None)
 def test_resolve_never_overshards(a, b):
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = _mesh1()
     spec = resolve((a * 3, b * 5), ("heads", "mlp"), mesh, DEFAULT_RULES)
     assert len(spec) == 2
 
@@ -131,7 +137,7 @@ def test_resolve_suffix_fallback():
     import numpy as np
     from jax.sharding import PartitionSpec
     # simulate with a 1x1 mesh: suffix fallback cannot find >1 divisor
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = _mesh1()
     rules = dict(DEFAULT_RULES, expert=("data", "model"))
     spec = resolve((32, 8, 8), ("expert", "embed", "moe_mlp"), mesh, rules)
     assert spec == PartitionSpec(None, None, None)
@@ -148,7 +154,9 @@ def test_microbatch_clamp_respects_dp_extent():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=64"
         import jax
         from repro.launch.cells import build_cell
-        mesh = jax.make_mesh((2, 16, 2), ("pod", "data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 16, 2), ("pod", "data", "model"),
+                             axis_types=(AxisType.Auto,) * 3)
         # granite default mb=4: global 256 / 4 = 64 >= 32 dp -> kept
         c1 = build_cell("granite-moe-1b-a400m", "train_4k", mesh)
         assert c1.meta["microbatches"] == 4, c1.meta
@@ -163,7 +171,7 @@ def test_microbatch_clamp_respects_dp_extent():
     """)
     proc = subprocess.run([sys.executable, "-c", script],
                           capture_output=True, text=True, timeout=600,
-                          env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                               "HOME": "/root"})
+                          env={**os.environ, "PYTHONPATH": "src",
+                               "JAX_PLATFORMS": "cpu"})
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "CLAMP-OK" in proc.stdout
