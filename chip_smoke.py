@@ -13,14 +13,23 @@ requests with prompts of 32-256 tokens and 16-64 new tokens.  Checked:
 * every token in ``[0, vocab)``;
 * each request's first token equals the sequential server's (both come
   from the same batch-1 prefill program, so they must be equal);
-* the prefill logits of one prompt agree with the same weights run in
-  float32 on the host CPU (a child process with ``JAX_PLATFORMS=cpu``).
-  bf16 itself moves logits, by an amount that depends on the model, so
-  the bound is relative to it: the chip's relative RMS distance from the
-  float32 logits may be at most ``LOGITS_SLACK`` times that of the same
-  bf16 program run on the host CPU (and never less than
-  ``LOGITS_FLOOR``).  Wrong weights, a wrong layer or a wrong cache give
-  a distance near 1.4, far outside it.
+* logits on the serving engine's own programs agree with a float32 run
+  on the host CPU (a child process with ``JAX_PLATFORMS=cpu``): the
+  prefill of one prompt, and one batched decode step for that request
+  sitting in slot ``PROBE_SLOT`` among ``SLOTS`` live requests of other
+  lengths (its cache spliced in, as serving does).  Twice:
+  - as served, in bf16.  bf16 itself moves random-weight logits far
+    (about 0.25 relative RMS at 24 layers), so this bound is relative:
+    at most ``LOGITS_SLACK`` times the distance of the same bf16 model
+    run on the host CPU on the same tokens (and never less than
+    ``LOGITS_FLOOR``).  It catches gross faults only;
+  - the same engine and programs in float32 at the highest matmul
+    precision, held to ``F32_BOUND`` relative RMS: float32 rounding,
+    amplified as much as bf16's is, stays orders of magnitude below it,
+    while one wrong layer or a bf16 computation lands far above;
+* a control that must fail the float32 check does: the prompt's prefill
+  with one layer's weights exchanged with the next layer's.  The same
+  control under the bf16 bound is reported, not gated.
 
 How often later tokens agree with the sequential server is reported, not
 gated: the batch-8 and batch-1 decode programs round differently in
@@ -63,8 +72,9 @@ ARCH = "stablelm-1.6b"
 CLIENTS, SLOTS, MAX_LEN, REQUESTS = 2, 8, 1024, 16
 PROMPT_LENS = (32, 64, 128, 256)
 LOGITS_SLACK, LOGITS_FLOOR = 2.0, 0.02
+F32_BOUND, F32_MAX_LEN = 1e-2, 512
+PROBE_SLOT = 5
 TRAIN_ARCH, TRAIN_LAYERS, TRAIN_STEPS = "mamba2-370m", 12, 3
-HB_TIMEOUT = 120.0
 
 
 class SmokeFailure(RuntimeError):
@@ -95,10 +105,11 @@ def require_tpu() -> Dict[str, Any]:
     return info
 
 
-def peak_bytes() -> Optional[int]:
+def memory() -> str:
     import jax
     stats = jax.devices()[0].memory_stats() or {}
-    return stats.get("peak_bytes_in_use")
+    return (f"bytes in use {stats.get('bytes_in_use')}, peak "
+            f"{stats.get('peak_bytes_in_use')}")
 
 
 class CompileClock:
@@ -184,41 +195,134 @@ def compare_tokens(served: Sequence[Dict[str, Any]],
     return out
 
 
-def probe_prompt(seed: int, vocab: int) -> List[int]:
-    """The prompt whose prefill logits are compared: the first request."""
+def probe_requests(seed: int, vocab: int) -> List[Dict[str, Any]]:
+    """The first ``SLOTS`` requests of the load; the first is the probe."""
     from repro.serve import all_requests
-    return all_requests(smoke_load(seed), CLIENTS, vocab)[0]["prompt"]
+    return all_requests(smoke_load(seed), CLIENTS, vocab)[:SLOTS]
 
 
-def prefill_logits(*, reduced: bool, seed: int, prompt: Sequence[int],
-                   f32: bool = False) -> np.ndarray:
-    """Last-position prefill logits of ``prompt`` with the serving weights
-    (``model.init`` from ``seed``).  ``f32`` casts those bf16 weights to
-    float32 and runs a float32 model at the highest matmul precision."""
+def decode_input(prompt: Sequence[int]) -> int:
+    """The token a probe slot decodes next (any token will do; this one
+    is known before the chip has run)."""
+    return int(prompt[0])
+
+
+def reference_sequences(seed: int, vocab: int) -> List[List[int]]:
+    """The token sequences whose last-position logits the CPU reference
+    computes: the probe prompt (prefill) and the probe prompt followed by
+    its decode input (one decode step)."""
+    prompt = list(probe_requests(seed, vocab)[0]["prompt"])
+    return [prompt, prompt + [decode_input(prompt)]]
+
+
+def swap_layers(params, layer: int):
+    """``params`` with the weights of layers ``layer`` and ``layer + 1``
+    exchanged: the control, a model with a wrong layer.  Donates
+    ``params``: the caller's tree is gone afterwards."""
+    import jax
+    paths = [p for p, _ in jax.tree_util.tree_leaves_with_path(params)
+             if any(getattr(k, "key", "").startswith("seg") for k in p)]
+    check(bool(paths), "no stacked layer weights to swap")
+
+    def swap(tree):
+        def one(path, a):
+            if path not in paths:
+                return a
+            return a.at[layer].set(a[layer + 1]).at[layer + 1].set(a[layer])
+        return jax.tree_util.tree_map_with_path(one, tree)
+    return jax.jit(swap, donate_argnums=0)(params)
+
+
+def logits_phase(*, reduced: bool, seed: int, max_len: int = MAX_LEN,
+                 f32: bool = False) -> Dict[str, np.ndarray]:
+    """Logits of the serving engine's own prefill and decode programs
+    (weights ``model.init`` from ``seed``, as ``run_serve`` makes them;
+    ``f32``: a float32 model at the highest matmul precision): the probe
+    prompt's prefill; one decode step with the probe request in
+    ``PROBE_SLOT`` among ``SLOTS`` live requests, each spliced in after
+    its own prefill; and last the control, the probe prefill with two
+    layers exchanged.  ``decode_tokens`` is what the serving decode step
+    itself emitted for that batch, ``decode_argmax`` the argmax of the
+    decode logits."""
+    import jax
+    import jax.numpy as jnp
+    from repro.serve import ServeEngine
+    cfg = model_cfg(reduced, max_len)
+    ctx = contextlib.nullcontext()
+    if f32:
+        cfg = cfg.replace(dtype="float32")
+        ctx = jax.default_matmul_precision("highest")
+    reqs = probe_requests(seed, cfg.vocab)
+    toks = {"tokens": jnp.asarray(np.asarray(reqs[0]["prompt"],
+                                             np.int32)[None])}
+    out = {}
+    with ctx:
+        eng = ServeEngine(cfg, slots=SLOTS, max_len=max_len, seed=seed)
+        out["prefill"] = np.asarray(
+            eng._prefill(eng.params, toks)[0][0, -1], np.float32)
+        order = reqs[1:]
+        order.insert(PROBE_SLOT, reqs[0])
+        for slot, r in enumerate(order):
+            _, pcache = eng.prefill(r["prompt"])
+            eng.attach(slot, len(r["prompt"]), decode_input(r["prompt"]),
+                       pcache)
+            del pcache
+        logits, _ = jax.jit(eng.model.decode_step)(
+            eng.params, eng.caches, jnp.asarray(eng.tokens),
+            jnp.asarray(eng.pos))
+        out["decode"] = np.asarray(logits[PROBE_SLOT, -1], np.float32)
+        out["decode_argmax"] = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
+        out["decode_tokens"] = np.asarray(eng.step(range(SLOTS)))
+        del logits
+        eng.caches = None
+        bad = swap_layers(eng.params, (cfg.n_layers - 1) // 2)
+        eng.params = None
+        out["control"] = np.asarray(eng._prefill(bad, toks)[0][0, -1],
+                                    np.float32)
+    return out
+
+
+def reference_logits(*, reduced: bool, seed: int,
+                     seqs: Sequence[Sequence[int]],
+                     dtype: str = "bfloat16") -> np.ndarray:
+    """Last-position prefill logits of each of ``seqs``, one row per
+    sequence.  ``bfloat16``: the serving weights (``model.init`` from
+    ``seed``) and model; ``float32-cast``: those bf16 weights cast to
+    float32 in a float32 model; ``float32``: a float32 model with its own
+    ``model.init`` from ``seed``, as ``logits_phase(f32=True)`` makes it.
+    Float32 runs use the highest matmul precision."""
     import jax
     import jax.numpy as jnp
     from repro.models import build_model
     from repro.train import make_prefill_step
     cfg = model_cfg(reduced)
+    ctx = contextlib.nullcontext()
+    if dtype == "float32":
+        cfg = cfg.replace(dtype="float32")
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(seed))
-    ctx = contextlib.nullcontext()
-    if f32:
+    if dtype == "float32-cast":
         model = build_model(cfg.replace(dtype="float32"))
         params = jax.tree.map(lambda a: a.astype(jnp.float32), params)
+    if dtype != "bfloat16":
         ctx = jax.default_matmul_precision("highest")
-    toks = jnp.asarray(np.asarray(prompt, np.int32)[None])
+    step = jax.jit(make_prefill_step(model))
+    rows = []
     with ctx:
-        logits, _ = jax.jit(make_prefill_step(model))(params,
-                                                      {"tokens": toks})
-    return np.asarray(logits, np.float32).reshape(-1)
+        for seq in seqs:
+            toks = jnp.asarray(np.asarray(seq, np.int32)[None])
+            logits, _ = step(params, {"tokens": toks})
+            rows.append(np.asarray(logits[0, -1], np.float32))
+    return np.stack(rows)
 
 
-def start_cpu_reference(*, reduced: bool, seed: int, prompt: Sequence[int],
+def start_cpu_reference(*, reduced: bool, seed: int,
+                        seqs: Sequence[Sequence[int]],
                         workdir: str) -> subprocess.Popen:
-    """Start the host-CPU logits of ``prompt`` (float32 reference and the
-    same bf16 program) in a child that never touches the chip."""
-    np.save(os.path.join(workdir, "prompt.npy"), np.asarray(prompt))
+    """Start the host-CPU logits of ``seqs`` (float32 references and the
+    same bf16 model) in a child that never touches the chip."""
+    with open(os.path.join(workdir, "seqs.json"), "w") as f:
+        json.dump([list(map(int, q)) for q in seqs], f)
     cmd = [sys.executable, os.path.abspath(__file__), "--cpu-reference",
            workdir, "--seed", str(seed)] + (["--reduced"] if reduced else [])
     return subprocess.Popen(cmd, env={**os.environ, "JAX_PLATFORMS": "cpu"})
@@ -237,11 +341,15 @@ def finish_cpu_reference(proc: subprocess.Popen,
         return {k: z[k] for k in z.files}
 
 
+REFERENCES = {"f32": "float32-cast", "bf16": "bfloat16", "f32_own": "float32"}
+
+
 def cpu_reference_main(workdir: str, seed: int, reduced: bool) -> int:
-    prompt = np.load(os.path.join(workdir, "prompt.npy")).tolist()
-    f32 = prefill_logits(reduced=reduced, seed=seed, prompt=prompt, f32=True)
-    bf16 = prefill_logits(reduced=reduced, seed=seed, prompt=prompt)
-    np.savez(os.path.join(workdir, "ref.npz"), f32=f32, bf16=bf16)
+    with open(os.path.join(workdir, "seqs.json")) as f:
+        seqs = json.load(f)
+    out = {k: reference_logits(reduced=reduced, seed=seed, seqs=seqs,
+                               dtype=d) for k, d in REFERENCES.items()}
+    np.savez(os.path.join(workdir, "ref.npz"), **out)
     return 0
 
 
@@ -249,23 +357,66 @@ def rel_rms(a: np.ndarray, ref: np.ndarray) -> float:
     return float(np.linalg.norm(a - ref) / np.linalg.norm(ref))
 
 
-def compare_logits(chip: np.ndarray,
-                   ref: Dict[str, np.ndarray]) -> Dict[str, Any]:
-    """The chip's logits against the float32 reference, bounded by what
-    bf16 costs the same program on the host CPU."""
-    f32 = ref["f32"]
-    spread = rel_rms(ref["bf16"], f32)
+def compare_logits(chip: np.ndarray, f32: np.ndarray, bound: float,
+                   what: str) -> Dict[str, Any]:
+    """The chip's logits against a float32 reference, within ``bound``
+    relative RMS."""
     out = {"max_abs_diff": float(np.max(np.abs(chip - f32))),
            "max_abs_ref": float(np.max(np.abs(f32))),
-           "rel_rms": rel_rms(chip, f32),
-           "cpu_bf16_rel_rms": spread,
-           "bound": max(LOGITS_SLACK * spread, LOGITS_FLOOR),
+           "rel_rms": rel_rms(chip, f32), "bound": bound,
            "argmax_equal": bool(np.argmax(chip) == np.argmax(f32))}
-    check(bool(np.all(np.isfinite(chip))), "non-finite logits on the chip")
-    check(out["rel_rms"] <= out["bound"],
-          f"chip logits are {out['rel_rms']:.4f} (relative RMS) from the "
-          f"float32 reference; the bound is {out['bound']:.4f}")
+    check(bool(np.all(np.isfinite(chip))), f"non-finite {what} logits")
+    check(out["rel_rms"] <= bound,
+          f"{what} logits are {out['rel_rms']:.4g} (relative RMS) from the "
+          f"float32 reference; the bound is {bound:.4g}")
     return out
+
+
+def check_logits(bf16: Dict[str, np.ndarray], f32: Dict[str, np.ndarray],
+                 ref: Dict[str, np.ndarray]) -> Dict[str, Dict[str, Any]]:
+    """bf16 prefill and decode within what bf16 costs on the host CPU;
+    float32 prefill and decode within ``F32_BOUND``; the float32 control
+    outside it (the bf16 control is only measured)."""
+    out = {}
+    for i, what in enumerate(("prefill", "decode")):
+        spread = rel_rms(ref["bf16"][i], ref["f32"][i])
+        out[what] = compare_logits(
+            bf16[what], ref["f32"][i],
+            max(LOGITS_SLACK * spread, LOGITS_FLOOR), f"bf16 {what}")
+        out[what]["cpu_bf16_rel_rms"] = spread
+        out[f"f32 {what}"] = compare_logits(
+            f32[what], ref["f32_own"][i], F32_BOUND, f"float32 {what}")
+    ctl = rel_rms(f32["control"], ref["f32_own"][0])
+    out["control"] = {"rel_rms": ctl, "bound": F32_BOUND,
+                      "bf16_rel_rms": rel_rms(bf16["control"],
+                                              ref["f32"][0]),
+                      "bf16_bound": out["prefill"]["bound"]}
+    check(ctl > F32_BOUND,
+          f"the control (two layers exchanged) reads {ctl:.4g}, within the "
+          f"float32 bound {F32_BOUND:.4g}: the check cannot tell it from a "
+          f"sound run")
+    return out
+
+
+def say_logits(lg: Dict[str, Dict[str, Any]],
+               bf16: Dict[str, np.ndarray], seqs: Sequence[Sequence[int]]
+               ) -> None:
+    for what, n in (("prefill", len(seqs[0])), ("decode", len(seqs[1]))):
+        x, y = lg[what], lg[f"f32 {what}"]
+        say(f"{what} logits ({n} tokens, vocab {bf16[what].size}): bf16 "
+            f"relative RMS {x['rel_rms']} vs bound {x['bound']} (CPU bf16: "
+            f"{x['cpu_bf16_rel_rms']}), max-abs-diff {x['max_abs_diff']} "
+            f"(max |ref| {x['max_abs_ref']}), argmax equal "
+            f"{x['argmax_equal']}; float32 relative RMS {y['rel_rms']} vs "
+            f"bound {y['bound']}, max-abs-diff {y['max_abs_diff']}, argmax "
+            f"equal {y['argmax_equal']}")
+    c = lg["control"]
+    say(f"control, layers exchanged: float32 relative RMS {c['rel_rms']} "
+        f"(must exceed {c['bound']}); bf16 relative RMS {c['bf16_rel_rms']} "
+        f"vs the bf16 bound {c['bf16_bound']} (not gated); the serving "
+        f"decode step's tokens equal the bf16 decode logits' argmax in "
+        f"{int(np.sum(bf16['decode_tokens'] == bf16['decode_argmax']))}/"
+        f"{SLOTS} slots")
 
 
 def one_chip(seed: int, info: Dict[str, Any]) -> Dict[str, Any]:
@@ -279,9 +430,9 @@ def one_chip(seed: int, info: Dict[str, Any]) -> Dict[str, Any]:
     clock = CompileClock()
     jax.monitoring.register_event_duration_secs_listener(clock)
     with tempfile.TemporaryDirectory(prefix="chip_smoke_") as workdir:
-        prompt = probe_prompt(seed, cfg.vocab)
-        ref_proc = start_cpu_reference(reduced=False, seed=seed,
-                                       prompt=prompt, workdir=workdir)
+        seqs = reference_sequences(seed, cfg.vocab)
+        ref_proc = start_cpu_reference(reduced=False, seed=seed, seqs=seqs,
+                                       workdir=workdir)
         try:
             t0 = time.monotonic()
             out = serve_phase(reduced=False, seed=seed)
@@ -291,7 +442,7 @@ def one_chip(seed: int, info: Dict[str, Any]) -> Dict[str, Any]:
                 f"slots {res['slots']}, max_len {MAX_LEN}; "
                 f"{time.monotonic() - t0:.1f}s with compiles; "
                 f"compile {clock.seconds:.1f}s in {clock.count} programs; "
-                f"peak bytes {peak_bytes()}")
+                f"{memory()}")
             served = res["records"]
             del out, res
             gc.collect()
@@ -300,22 +451,21 @@ def one_chip(seed: int, info: Dict[str, Any]) -> Dict[str, Any]:
             say(f"sequential: first tokens {tok['first_token_matches']}/"
                 f"{tok['requests']} equal; token agreement "
                 f"{tok['token_agreement']} over {tok['tokens_compared']} "
-                f"positions (reported, not gated)")
+                f"positions (reported, not gated); {memory()}")
             del seq
             gc.collect()
-            chip = prefill_logits(reduced=False, seed=seed, prompt=prompt)
+            bf16 = logits_phase(reduced=False, seed=seed)
+            gc.collect()
+            f32 = logits_phase(reduced=False, seed=seed,
+                               max_len=F32_MAX_LEN, f32=True)
         except BaseException:
             ref_proc.kill()
             raise
         ref = finish_cpu_reference(ref_proc, workdir)
-    lg = compare_logits(chip, ref)
-    say(f"logits ({len(prompt)}-token prompt, vocab {chip.size}): "
-        f"max-abs-diff {lg['max_abs_diff']} (max |ref| "
-        f"{lg['max_abs_ref']}), relative RMS {lg['rel_rms']} vs bound "
-        f"{lg['bound']} (CPU bf16: {lg['cpu_bf16_rel_rms']}), argmax "
-        f"equal {lg['argmax_equal']}")
+    lg = check_logits(bf16, f32, ref)
+    say_logits(lg, bf16, seqs)
     say(f"compile {clock.seconds:.1f}s in {clock.count} programs; "
-        f"peak bytes {peak_bytes()}")
+        f"{memory()}")
     return info
 
 
@@ -355,12 +505,14 @@ def pinned_trainer(model_cfg, data_cfg, opt_cfg, trainer_cfg, tpu: bool):
     process holds, then builds the trainer."""
     import jax
     from repro.runtime_dist import trainer_program
+    t0 = time.time()
     d = jax.devices()
     say(f"rank process {os.environ.get('EDAT_LOCAL_RANKS')}: "
         f"{len(d)} device(s), id {d[0].id}, {d[0].platform} "
         f"{d[0].device_kind}, TPU_VISIBLE_CHIPS="
         f"{os.environ.get('TPU_VISIBLE_CHIPS')}, device files "
-        f"{open_chip_files()}")
+        f"{open_chip_files()}; backend start from wall time {t0:.3f} "
+        f"took {time.time() - t0:.3f}s")
     check(not tpu or d[0].platform == "tpu",
           f"rank process on {d[0].platform}, not a TPU")
     return trainer_program(model_cfg, data_cfg, opt_cfg, trainer_cfg)
@@ -375,16 +527,25 @@ def four_chip_phase(seed: int, *, tpu: bool = True,
     from repro.runtime_dist import EventDrivenTrainer, flatten_params
     cfgs = trainer_cfgs(seed, **cfg_kw)
     model_cfg_, data, opt, trainer = cfgs
-    t0 = time.monotonic()
-    # A process starting libtpu can go quiet for longer than the socket
-    # transport's default 5 s heartbeat timeout; its peers then declare it
-    # dead and each replica trains alone.
+    t0, wall0 = time.monotonic(), time.time()
     with edat.Session(4, procs=4, transport="socket", timeout=1000.0,
                       workers_per_rank=trainer.workers_per_rank,
-                      unconsumed="ignore", hb_timeout=HB_TIMEOUT) as s:
+                      unconsumed="ignore") as s:
         s.run(edat.deferred(pinned_trainer, *cfgs, tpu))
         res = s.gather()
+        net = s.stats()["transport"]
     t_dist = time.monotonic() - t0
+    # the socket transport's own log of each rank process: when its
+    # heartbeat loop was frozen, and when it had told its peers it may be
+    def since(x):
+        return None if x is None else round(x - wall0, 3)
+    say("heartbeat-loop stalls by rank process (seconds after the round "
+        "started, seconds frozen): "
+        + str({p: [(since(t), round(x, 3)) for t, x in log]
+               for p, log in net.get("hb_stalls", {}).items()}))
+    say("quiet windows by rank process (seconds after the round started): "
+        + str({p: [(since(a), since(b)) for a, b in log]
+               for p, log in net.get("quiet", {}).items()}))
     say("socket round (rank, step, gradients folded): "
         f"{[(m['rank'], m['step'], m['n_grads']) for m in res['history']]}")
     check(sorted(res["final_params"]) == [0, 1, 2, 3],

@@ -155,21 +155,26 @@ class _SessionMain:
 
     # -- SPMD main ------------------------------------------------------------
     def __call__(self, ctx: Context) -> None:
-        prog = self.resolved()
-        if prog is not None:
-            chans = getattr(prog, "channels", None)
-            if chans:
-                ctx.declare_channels(chans)
-        if ctx.rank == 0 and self.calls:
-            ctx.submit_persistent(self._collect,
-                                  deps=[(ANY, "__sess.result")],
-                                  name="__sess.collector")
-        for cid, rank, fn, deps in self.calls:
-            if rank == ctx.rank:
-                ctx.submit(self._call_task(cid, fn), deps=deps)
-        if prog is not None:
-            prog.start(ctx)
-        elif self.mainfn is not None:
+        # building and starting a program may hold the interpreter for
+        # long (an accelerator backend starting, weights being made): the
+        # peers' failure detectors must not read that silence as a death
+        with ctx._rt.transport.quiet():
+            prog = self.resolved()
+            if prog is not None:
+                chans = getattr(prog, "channels", None)
+                if chans:
+                    ctx.declare_channels(chans)
+            if ctx.rank == 0 and self.calls:
+                ctx.submit_persistent(self._collect,
+                                      deps=[(ANY, "__sess.result")],
+                                      name="__sess.collector")
+            for cid, rank, fn, deps in self.calls:
+                if rank == ctx.rank:
+                    ctx.submit(self._call_task(cid, fn), deps=deps)
+            if prog is not None:
+                prog.start(ctx)
+                return
+        if self.mainfn is not None:
             self.mainfn(ctx)
 
     def _call_task(self, cid: int, fn: Callable) -> Callable:

@@ -177,6 +177,9 @@ def merge_metrics(parts: Iterable[Tuple[int, Dict[str, Any]]]
                                              t["sendq_max"])
             for p, pm in (t.get("peers") or {}).items():
                 transport.setdefault("peers", {})[f"{lead}->{p}"] = dict(pm)
+            for k in ("hb_stalls", "quiet"):
+                if t.get(k):
+                    transport.setdefault(k, {})[lead] = list(t[k])
     out = {"channels": channels, "ranks": ranks, "transport": transport}
     if durable:
         out["durable"] = durable

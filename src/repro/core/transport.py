@@ -51,10 +51,11 @@ through CONTROL messages instead of shared memory.
 from __future__ import annotations
 
 import abc
+import contextlib
 import dataclasses
 import threading
 from collections import deque
-from typing import Any, Callable, List, Optional
+from typing import Any, Callable, ContextManager, List, Optional
 
 # message kinds
 EVENT = "event"            # user event (counted for termination)
@@ -139,6 +140,13 @@ class Transport(abc.ABC):
         OS, or ``timeout`` expires.  Transports that write synchronously
         inside :meth:`send` have nothing to wait for — returns True."""
         return True
+
+    def quiet(self) -> ContextManager[None]:
+        """Context manager around work that may hold the interpreter for
+        long (an accelerator backend starting, a program being built): a
+        transport whose failure detector listens for heartbeats must not
+        read that silence as a death.  Nothing listens here: a no-op."""
+        return contextlib.nullcontext()
 
     def validate_payload(self, data: Any) -> None:
         """Raise ``TypeError`` if ``data`` cannot travel on this transport.

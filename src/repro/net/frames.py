@@ -61,6 +61,8 @@ MSGS = "msgs"            # decoded batch frames: ("msgs", [obj, ...])
 HELLO = "hello"
 HEARTBEAT = "hb"
 BYE = "bye"
+#: ("quiet", on): the sender may go silent (on) or beats again (off)
+QUIET = "quiet"
 
 # elastic join (late processes re-hosting a dead process's ranks):
 JOIN = "join"                  # ("join", lead, ranks, addr) -> coordinator

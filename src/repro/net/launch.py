@@ -39,8 +39,10 @@ placement.
 
 On a host with TPU chips, a chip belongs to one process at a time, so
 each spawned process is pinned to its own chip (placement entry ``p`` to
-chip ``p % n_chips``) through libtpu's per-process visibility
-environment; the launching parent must stay off JAX while they run.
+chip ``p``) through libtpu's per-process visibility environment, and a
+launch of more processes than chips is refused; the launching parent
+must stay off JAX while they run.  Children held off the TPU by
+``JAX_PLATFORMS`` are neither pinned nor counted.
 """
 from __future__ import annotations
 
@@ -71,9 +73,13 @@ def _free_port(host: str = "127.0.0.1") -> int:
 
 
 def tpu_chip_count() -> int:
-    """TPU chips on this host, counted from their device files: nothing
-    here may start JAX, since the launching parent must not hold a chip
-    its children need."""
+    """TPU chips the spawned processes may reach for: this host's, counted
+    from their device files (nothing here may start JAX, since the
+    launching parent must not hold a chip its children need), or none
+    when ``JAX_PLATFORMS`` names no TPU."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
     return (len(glob.glob("/dev/accel[0-9]*"))
             or len(glob.glob("/dev/vfio/[0-9]*")))
 
@@ -250,13 +256,20 @@ class ProcessGroup:
     def _child_env(self, lead: int) -> Dict[str, str]:
         """Per-process environment: on a TPU host, libtpu's visibility
         variables that give the process one chip alone, as a one-chip
-        slice with a port of its own."""
+        slice with a port of its own.  Raises ``RuntimeError`` when the
+        host has fewer chips than the launch has processes."""
         n_chips = tpu_chip_count()
         if not n_chips:
             return {}
+        if len(self.placement) > n_chips:
+            raise RuntimeError(
+                f"{len(self.placement)} rank processes on a host with "
+                f"{n_chips} TPU chip(s): a chip belongs to one process at "
+                f"a time.  Launch at most {n_chips} processes, or hold "
+                f"them to the CPU with JAX_PLATFORMS=cpu.")
         index = [rs[0] for rs in self.placement].index(lead)
         port = _free_port(self._host)
-        return {"TPU_VISIBLE_CHIPS": str(index % n_chips),
+        return {"TPU_VISIBLE_CHIPS": str(index),
                 "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
                 "TPU_PROCESS_BOUNDS": "1,1,1",
                 "TPU_PROCESS_PORT": str(port),

@@ -47,7 +47,16 @@ stream sockets with length-prefixed pickled frames (:mod:`repro.net.frames`):
   ``on_peer_dead`` fires once per hosted rank, which the runtime wires to
   its ``RANK_FAILED`` machinery — survivors see one failure event per
   lost rank, exactly like ``kill_rank``.  Sends to dead ranks are dropped
-  and counted, mirroring ``InProcTransport``.
+  and counted, mirroring ``InProcTransport``.  Heartbeats come from a
+  Python thread, so a native call that holds the interpreter (starting
+  libtpu, for one) silences them; two rules keep such a stall from
+  reading as a death.  A process never counts its *own* frozen time
+  against its peers (the heartbeat loop measures how late it woke and
+  moves every peer's last-seen time forward by as much).  And work that
+  may freeze the process runs under :meth:`quiet`: the process tells its
+  peers first, and they judge it by its connection alone (EOF or a broken
+  connection) until it says it beats again.  :meth:`metrics` lists the
+  stalls and quiet windows with wall-clock times.
 * **Termination accounting** — per-peer ``sent_to``/``recv_from`` vectors
   (user events only; sent counts at *enqueue*, before the wire write, and
   received counts when a message is *popped* for delivery, so queued and
@@ -75,12 +84,14 @@ Construction is normally via :func:`repro.net.bootstrap.bootstrap` (or
 """
 from __future__ import annotations
 
+import contextlib
 import pickle
 import socket
 import threading
 import time
 from collections import deque
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (Callable, Dict, Iterator, List, Optional, Sequence,
+                    Tuple)
 
 import numpy as np
 
@@ -207,6 +218,15 @@ class SocketTransport(Transport):
 
         self._hb_interval = hb_interval
         self._hb_timeout = hb_timeout
+        #: peer processes that announced a quiet window (judged by their
+        #: connection alone until they announce its end)
+        self._quiet_peers: set = set()
+        self._quiet_mu = threading.Lock()   # serialises announcements
+        self._quiet_depth = 0               # threads inside quiet() here
+        #: bounded logs for metrics(): [wall time woken, seconds frozen]
+        #: per heartbeat-loop stall, [wall start, wall end] per quiet window
+        self._hb_stalls: List[List[float]] = []
+        self._quiet_log: List[List[Optional[float]]] = []
         self._threads: List[threading.Thread] = []
         #: live reader/writer threads per peer process — add_peer joins a
         #: dead peer's old threads before spawning replacements, so one
@@ -314,6 +334,12 @@ class SocketTransport(Transport):
                     with self._mu:
                         self._bye.add(peer)
                     # keep reading until EOF so late frames cannot be lost
+                elif kind == frames.QUIET:
+                    with self._mu:
+                        if frame[1]:
+                            self._quiet_peers.add(peer)
+                        else:
+                            self._quiet_peers.discard(peer)
                 elif kind == frames.PEER_JOINED:
                     # the coordinator announced an elastic rejoin: dial the
                     # replacement off-thread (the dial blocks) and splice
@@ -336,13 +362,26 @@ class SocketTransport(Transport):
 
     def _heartbeat_loop(self) -> None:
         beat = frames.encode((frames.HEARTBEAT,))
+        prev = time.monotonic()
         while not self._hb_stop.wait(self._hb_interval):
             now = time.monotonic()
+            late = now - prev - self._hb_interval
+            prev = now
+            if late > self._hb_interval:
+                # this process was frozen: its readers could not take in
+                # the peers' beats either, so that span is no evidence
+                with self._mu:
+                    for p in self._last_seen:
+                        self._last_seen[p] = min(now,
+                                                 self._last_seen[p] + late)
+                    if len(self._hb_stalls) < self._LOG_CAP:
+                        self._hb_stalls.append([time.time(), late])
             for p in list(self._peers):
                 with self._mu:
                     if self._sock_dead[p] or p in self._bye or self._closing:
                         continue
-                    stale = now - self._last_seen[p] > self._hb_timeout
+                    stale = (p not in self._quiet_peers
+                             and now - self._last_seen[p] > self._hb_timeout)
                 if stale:
                     self._declare_proc_dead(p)
                     continue
@@ -354,6 +393,52 @@ class SocketTransport(Transport):
                         self._peers[p].sendall(beat)
                 except OSError:
                     self._declare_proc_dead(p)
+
+    _LOG_CAP = 64
+
+    @contextlib.contextmanager
+    def quiet(self) -> Iterator[None]:
+        """Run the body as a quiet window: this process may go silent
+        inside it, so its peers judge it by its connection alone (a death
+        still shows as EOF or a broken connection) instead of by heartbeat
+        silence.  The announcement reaches the kernel before the body
+        runs; windows of several threads nest into one."""
+        with self._quiet_mu:
+            self._quiet_depth += 1
+            if self._quiet_depth == 1:
+                self._announce((frames.QUIET, True))
+                with self._mu:
+                    if len(self._quiet_log) < self._LOG_CAP:
+                        self._quiet_log.append([time.time(), None])
+        try:
+            yield
+        finally:
+            with self._quiet_mu:
+                self._quiet_depth -= 1
+                if self._quiet_depth == 0:
+                    with self._mu:
+                        if self._quiet_log and self._quiet_log[-1][1] is None:
+                            self._quiet_log[-1][1] = time.time()
+                    self._announce((frames.QUIET, False), wait=False)
+
+    def _announce(self, obj, wait: bool = True) -> None:
+        """Send the control frame ``obj`` to every live peer process; with
+        ``wait``, return only once it has been handed to the kernel."""
+        frame = frames.encode(obj)
+        for p in list(self._peers):
+            with self._mu:
+                if self._sock_dead[p] or p in self._bye or self._closing:
+                    continue
+            if self.coalesce:
+                self._enqueue(p, [("enc", [frame], 0)])
+                continue
+            try:
+                with self._send_mu[p]:
+                    self._peers[p].sendall(frame)
+            except OSError:
+                self._declare_proc_dead(p)
+        if wait:
+            self.flush(timeout=self._hb_timeout)
 
     @staticmethod
     def _teardown(sock: socket.socket) -> None:
@@ -939,6 +1024,7 @@ class SocketTransport(Transport):
                 self._peers[lead] = sock
                 self._sock_dead[lead] = False
                 self._bye.discard(lead)
+                self._quiet_peers.discard(lead)
                 self._last_seen[lead] = time.monotonic()
                 for r in ranks:
                     self._sent_to[r] = 0
@@ -986,23 +1072,9 @@ class SocketTransport(Transport):
     def announce_join(self, lead: int, addr: Tuple[str, int]) -> None:
         """Broadcast ``PEER_JOINED`` to every live peer process: each one
         dials the newcomer at ``addr`` and splices it in (the coordinator
-        calls this after accepting an elastic JOIN)."""
-        frame = frames.encode((frames.PEER_JOINED, lead, tuple(addr)))
-        for p in list(self._peers):
-            if p == lead:
-                continue
-            with self._mu:
-                if (self._sock_dead.get(p, True) or p in self._bye
-                        or self._closing):
-                    continue
-            if self.coalesce:
-                self._enqueue(p, [("enc", [frame], 0)])
-                continue
-            try:
-                with self._send_mu[p]:
-                    self._peers[p].sendall(frame)
-            except OSError:
-                self._declare_proc_dead(p)
+        calls this after accepting an elastic JOIN, while the newcomer's
+        entry is still dead here, so it is not among those told)."""
+        self._announce((frames.PEER_JOINED, lead, tuple(addr)), wait=False)
 
     @property
     def dropped(self) -> int:
@@ -1035,7 +1107,8 @@ class SocketTransport(Transport):
         """Wire-level observability snapshot for this process (consumed by
         ``Runtime.metrics()`` / ``Session.stats()``): event totals split
         wire vs loopback, drop count, and per-peer-process bytes, write
-        batches, and send-queue high-water mark."""
+        batches, and send-queue high-water mark; the heartbeat loop's
+        stalls and this process's quiet windows, with wall-clock times."""
         with self._mu:
             return {
                 "kind": "socket",
@@ -1052,6 +1125,8 @@ class SocketTransport(Transport):
                               "writes": self._m_writes[p],
                               "sendq_max": self._m_sendq_max[p]}
                           for p in self._peers},
+                "hb_stalls": [list(x) for x in self._hb_stalls],
+                "quiet": [list(x) for x in self._quiet_log],
             }
 
     # -------------------------------------------------------------- close

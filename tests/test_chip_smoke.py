@@ -41,24 +41,69 @@ def test_compare_tokens_rejects_a_wrong_first_token(smoke):
 
 
 def test_prefill_logits_match_cpu_reference_child(smoke, tmp_path):
+    """The engine's prefill and batched decode logits, in both precisions,
+    against the CPU reference child, with the control outside the bound."""
     vocab = smoke.model_cfg(reduced=True).vocab
-    prompt = smoke.probe_prompt(0, vocab)
-    proc = smoke.start_cpu_reference(reduced=True, seed=0, prompt=prompt,
+    seqs = smoke.reference_sequences(0, vocab)
+    assert seqs[1][:-1] == seqs[0]
+    proc = smoke.start_cpu_reference(reduced=True, seed=0, seqs=seqs,
                                      workdir=str(tmp_path))
-    logits = smoke.prefill_logits(reduced=True, seed=0, prompt=prompt)
+    bf16 = smoke.logits_phase(reduced=True, seed=0, max_len=512)
+    f32 = smoke.logits_phase(reduced=True, seed=0, max_len=512, f32=True)
     ref = smoke.finish_cpu_reference(proc, str(tmp_path))
-    assert logits.shape == ref["f32"].shape == (vocab,)
-    got = smoke.compare_logits(logits, ref)
-    assert got["rel_rms"] <= got["bound"] and got["argmax_equal"]
+    for k in smoke.REFERENCES:
+        assert ref[k].shape == (2, vocab)
+    assert f32["prefill"].shape == bf16["decode"].shape == (vocab,)
+    lg = smoke.check_logits(bf16, f32, ref)
+    assert lg["f32 prefill"]["argmax_equal"]
+    assert lg["f32 decode"]["argmax_equal"]
+    assert lg["control"]["rel_rms"] > 10 * smoke.F32_BOUND
+    # the serving decode program emits the argmax of the probed logits
+    assert (bf16["decode_tokens"] == bf16["decode_argmax"]).all()
 
 
 def test_compare_logits_rejects_other_weights(smoke):
     rng = np.random.default_rng(0)
     f32 = rng.normal(size=1000).astype(np.float32)
-    ref = {"f32": f32, "bf16": f32 * (1 + 1e-3)}
-    smoke.compare_logits(f32 * (1 + 5e-3), ref)          # bf16-sized: passes
+    smoke.compare_logits(f32 * (1 + 5e-3), f32, 1e-2, "prefill")  # passes
     with pytest.raises(smoke.SmokeFailure, match="relative RMS"):
-        smoke.compare_logits(rng.normal(size=1000).astype(np.float32), ref)
+        smoke.compare_logits(rng.normal(size=1000).astype(np.float32),
+                             f32, 1e-2, "prefill")
+
+
+@pytest.mark.parametrize("fault", ["bf16 decode", "float32 prefill",
+                                   "control"])
+def test_check_logits_fails_a_wrong_run_or_a_blind_control(smoke, fault):
+    """A run off the reference in either precision fails the check, and so
+    does a control the bound cannot tell from a sound run."""
+    rng = np.random.default_rng(1)
+    f32 = rng.normal(size=(2, 1000)).astype(np.float32)
+    ref = {"f32": f32, "bf16": f32 * (1 + 1e-3), "f32_own": f32 * 2}
+    other = rng.normal(size=1000).astype(np.float32)
+    bf16_run = {"prefill": f32[0], "decode": f32[1], "control": other}
+    f32_run = {"prefill": 2 * f32[0], "decode": 2 * f32[1],
+               "control": other}
+    smoke.check_logits(bf16_run, f32_run, ref)
+    if fault == "bf16 decode":
+        bf16_run["decode"] = other
+    elif fault == "float32 prefill":
+        f32_run["prefill"] = 2 * f32[0] * (1 + 5e-2)
+    else:
+        f32_run["control"] = 2 * f32[0]
+    with pytest.raises(smoke.SmokeFailure, match=fault):
+        smoke.check_logits(bf16_run, f32_run, ref)
+
+
+def test_swap_layers_exchanges_only_the_two_layers(smoke):
+    import jax.numpy as jnp
+    params = {"embed": jnp.ones((3, 2)),
+              "seg0": {"w": jnp.arange(8.0).reshape(4, 2)}}
+    out = smoke.swap_layers(params, 1)
+    assert (out["seg0"]["w"] == jnp.asarray(
+        [[0, 1], [4, 5], [2, 3], [6, 7]], jnp.float32)).all()
+    assert (out["embed"] == 1).all()
+    with pytest.raises(smoke.SmokeFailure, match="no stacked layer"):
+        smoke.swap_layers({"embed": jnp.ones(2)}, 0)
 
 
 def test_script_refuses_a_cpu_platform():

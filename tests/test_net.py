@@ -16,6 +16,7 @@ Every cross-process test is guarded by the launcher's own join deadline
 import functools
 import os
 import socket
+import sys
 import threading
 import time
 
@@ -156,6 +157,111 @@ def test_heartbeat_detects_silent_peer():
     assert deaths == [0] and tb.is_dead(0)
     tb.close()
     ta.close()
+
+
+def _hold_interpreter(seconds: float) -> None:
+    """Keep every other thread of this process off the interpreter for
+    ``seconds``, as a native call that holds the GIL does (starting an
+    accelerator backend, for one)."""
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(seconds + 1.0)
+    try:
+        end = time.monotonic() + seconds
+        while time.monotonic() < end:
+            pass
+    finally:
+        sys.setswitchinterval(old)
+
+
+def test_own_stall_is_not_counted_against_peers():
+    """A process frozen for longer than ``hb_timeout`` must not declare its
+    peers dead when it wakes: they beat all along, it could not hear them.
+    The heartbeat loop logs the stall."""
+    ta, tb = _pair(hb_interval=0.1, hb_timeout=0.6)
+    deaths = []
+    ta.on_peer_dead = tb.on_peer_dead = deaths.append
+    try:
+        time.sleep(0.3)
+        _hold_interpreter(1.5)
+        time.sleep(1.0)                  # several beats after the stall
+        assert deaths == [] and not ta.is_dead(1) and not tb.is_dead(0)
+        stalls = tb.metrics()["hb_stalls"]
+        assert stalls and max(s for _, s in stalls) > 1.0
+    finally:
+        tb.close()
+        ta.close()
+
+
+def test_quiet_peer_is_judged_by_its_connection_alone():
+    """Inside ``quiet()`` a peer that never beats is not declared dead;
+    once the window closes its silence counts again."""
+    a, b = socket.socketpair()
+    ta = SocketTransport(0, 2, {1: a}, hb_interval=0)      # never beats
+    tb = SocketTransport(1, 2, {0: b}, hb_interval=0.1, hb_timeout=0.6)
+    deaths = []
+    tb.on_peer_dead = deaths.append
+    try:
+        with ta.quiet():
+            with ta.quiet():             # windows nest into one
+                time.sleep(1.5)
+            time.sleep(1.0)
+            assert deaths == [] and not tb.is_dead(0)
+        deadline = time.monotonic() + 10
+        while not deaths and time.monotonic() < deadline:
+            time.sleep(0.02)
+        assert deaths == [0]
+        (start, end), = ta.metrics()["quiet"]
+        assert end - start > 2.0
+    finally:
+        tb.close()
+        ta.close()
+
+
+def test_crash_inside_quiet_window_is_still_detected():
+    """A quiet window waives the heartbeat, not the connection: a peer
+    that dies inside one is declared dead at once."""
+    ta, tb = _pair(hb_interval=0.1, hb_timeout=30.0)
+    deaths = []
+    tb.on_peer_dead = deaths.append
+    try:
+        with ta.quiet():
+            time.sleep(0.2)
+            chaos.crash_socket(ta._peers[1])
+            deadline = time.monotonic() + 5
+            while not deaths and time.monotonic() < deadline:
+                time.sleep(0.01)
+        assert deaths == [0] and tb.is_dead(0)
+    finally:
+        tb.close()
+        ta.close()
+
+
+class _FrozenBuildRing:
+    """Ring relay whose construction first freezes the process hosting
+    rank 1 (only it, so its peer is awake to watch the silence)."""
+
+    def __init__(self, seconds: float, n_hops: int):
+        if os.environ.get("EDAT_RANK") == "1":
+            _hold_interpreter(seconds)
+        self.n_hops = n_hops
+
+    def start(self, ctx):
+        _ring_main(ctx, n_hops=self.n_hops)
+
+
+def test_frozen_program_build_is_not_a_death():
+    """A rank process freezes for longer than ``hb_timeout`` while it
+    builds its program: no rank is declared dead and the round completes.
+    Session stats carry every process's quiet window and the stall."""
+    with edat.Session(ranks=2, procs=2, transport="socket", timeout=60,
+                      hb_interval=0.2, hb_timeout=1.0) as s:
+        s.run(edat.deferred(_FrozenBuildRing, 2.5, 20))
+        stats = s.stats()
+    assert stats["events_sent"] == 20
+    assert sorted(stats["transport"]["quiet"]) == [0, 1]
+    stalls = [sec for log in stats["transport"]["hb_stalls"].values()
+              for _, sec in log]
+    assert max(stalls) > 1.0
 
 
 # --------------------------------------------------- rendezvous (threads)

@@ -306,15 +306,15 @@ def _record_chip_main(ctx, out_dir):
 
 def test_rank_processes_are_pinned_to_their_own_chip(monkeypatch, tmp_path):
     """On a TPU host each spawned process gets libtpu's visibility
-    variables for chip ``p % n_chips`` before it runs any user code; on a
-    host without chips nothing is set."""
+    variables for chip ``p`` before it runs any user code; on a host
+    without chips nothing is set."""
     from repro.net import launch
     pg = ProcessGroup(5, "unused:main", n_procs=3)     # (0,1) (2,3) (4,)
     monkeypatch.setattr(launch, "tpu_chip_count", lambda: 0)
     assert pg._child_env(0) == {}
-    monkeypatch.setattr(launch, "tpu_chip_count", lambda: 2)
+    monkeypatch.setattr(launch, "tpu_chip_count", lambda: 4)
     envs = [pg._child_env(rs[0]) for rs in pg.placement]
-    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "0"]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2"]
     for e in envs:
         assert e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
         assert e["TPU_PROCESS_BOUNDS"] == "1,1,1"
@@ -326,6 +326,34 @@ def test_rank_processes_are_pinned_to_their_own_chip(monkeypatch, tmp_path):
                      n_procs=2, timeout=60)
     seen = {r: (tmp_path / f"rank{r}").read_text() for r in range(4)}
     assert seen == {0: "0", 1: "0", 2: "1", 3: "1"}
+
+
+@pytest.mark.parametrize("platforms,chips", [
+    ("cpu", 0), ("", 2), ("tpu", 2), ("tpu,cpu", 2), ("cuda,cpu", 0)])
+def test_chips_are_counted_only_for_children_that_may_use_them(
+        monkeypatch, platforms, chips):
+    """``JAX_PLATFORMS`` without a TPU in it holds the children off the
+    chips: none is counted, so none is pinned."""
+    from repro.net import launch
+    monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    monkeypatch.setattr(launch.glob, "glob", lambda pat: (
+        ["/dev/accel0", "/dev/accel1"] if "accel" in pat else []))
+    assert launch.tpu_chip_count() == chips
+
+
+def test_launch_refuses_more_processes_than_chips(monkeypatch):
+    """Two processes must never share a chip: a launch of more rank
+    processes than the host has chips fails before spawning anything."""
+    from repro.net import launch
+    monkeypatch.setattr(launch, "tpu_chip_count", lambda: 1)
+    pg = ProcessGroup(2, "unused:main", n_procs=2)
+    with pytest.raises(RuntimeError, match="2 rank processes on a host "
+                                           "with 1 TPU chip"):
+        pg.start()
+    assert pg._procs == {}
+    # one process hosting both ranks fits the one chip
+    assert ProcessGroup(2, "unused:main", n_procs=1)._child_env(0)[
+        "TPU_VISIBLE_CHIPS"] == "0"
 
 
 def _square(x):
