@@ -1,0 +1,1 @@
+"""Modules found by name; see the package docstring."""
