@@ -31,6 +31,8 @@ from repro.core import (ALL, ANY, SELF, RANK_FAILED, Context, Dep,
                         TaskHandle, TimerHandle, Transport, dep)
 # -- collective patterns (previously deep-import only) -----------------------
 from repro.core.patterns import allreduce, barrier, tree_reduce, wait_barrier
+# -- spans of traced sessions (``Session(trace=True)``) -----------------------
+from repro.core.trace import span
 # -- distribution layer ------------------------------------------------------
 from repro.net import ProcessGroup, SocketTransport, launch_processes
 # -- v2 surface --------------------------------------------------------------
@@ -59,6 +61,8 @@ __all__ = [
     "InProcTransport", "Message", "Transport",
     # collectives + timers
     "barrier", "wait_barrier", "allreduce", "tree_reduce", "fire_after",
+    # spans
+    "span",
     # distribution layer
     "ProcessGroup", "SocketTransport", "launch_processes",
 ]

@@ -266,7 +266,8 @@ class Session:
         self.timeout = timeout
         #: always-on per-channel/rank/transport counters (``metrics=False``
         #: disables them for A/B overhead runs); ``trace=True`` additionally
-        #: records bounded per-rank task/event timelines in the stats
+        #: records bounded per-rank spans (:mod:`repro.core.trace`) in the
+        #: stats
         self.metrics = bool(metrics)
         self.trace = bool(trace)
         #: durable task log + automated replay (:mod:`repro.durable`):

@@ -25,8 +25,9 @@ spawn and teardown now belong to :class:`repro.api.Session`.
 This package holds the runtime itself: events/deps (:mod:`.event`),
 per-rank scheduling (:mod:`.scheduler`), indexed routing
 (:mod:`.router`), ranks/progress/termination/timers (:mod:`.runtime`),
-the pluggable transport interface (:mod:`.transport`) and collective
-patterns (:mod:`.patterns`).
+the pluggable transport interface (:mod:`.transport`), collective
+patterns (:mod:`.patterns`) and the opt-in span recorder
+(:mod:`.trace`).
 """
 from .event import ALL, ANY, SELF, RANK_FAILED, Dep, Event, dep
 from .router import EventRouter

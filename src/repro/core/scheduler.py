@@ -30,14 +30,11 @@ import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from . import trace as _trace
 from .event import ALL, ANY, SELF, Dep, Event
 from .router import EventRouter
 
 _inst_uid = itertools.count()
-
-#: per-rank cap on opt-in trace records; beyond it, records are counted
-#: (``trace_dropped``) instead of stored, bounding memory on long runs
-TRACE_CAP = 50_000
 
 
 class Slot:
@@ -209,7 +206,7 @@ class Waiter(Consumer):
 class Instance:
     """A task execution instance on the ready queue."""
 
-    __slots__ = ("fn", "events", "name", "uid", "mrec")
+    __slots__ = ("fn", "events", "name", "uid", "mrec", "ready_ns")
 
     def __init__(self, fn, events, name, mrec=None):
         self.fn = fn
@@ -220,6 +217,7 @@ class Instance:
         # qmax]) for single-dep instances dispatched straight from a
         # delivery: _run consume-counts through it without re-probing
         self.mrec = mrec
+        self.ready_ns = 0   # entry into the ready queue (traced runs only)
 
 
 class _TaskTLS(threading.local):
@@ -282,8 +280,7 @@ class Scheduler:
         #                                                    pending, qmax]
         self._m_quorum: Dict[int, float] = {}      # src rank -> wait seconds
         self._busy_s = 0.0
-        self._trace: List[tuple] = []
-        self._trace_dropped = 0
+        self._spans = _trace.Recorder() if trace else None
 
         #: durable-mode consume hook (repro.durable): called OUTSIDE the
         #: scheduler lock with the just-consumed events, on every path that
@@ -348,9 +345,6 @@ class Scheduler:
         refires: List[Event] = []
         with self._mu:
             self.received += len(evs)
-            if self.trace_on:
-                self._trace_add_locked(
-                    ("recv", time.monotonic(), len(evs), evs[0].eid))
             if self.metrics_on:
                 # account runs of equal eids and offer their events in one
                 # pass: coalesced deliveries are near-always single-channel
@@ -391,8 +385,7 @@ class Scheduler:
                 for ev in evs:
                     self._offer_locked(ev, ready, wake, refires)
             if ready:
-                self._ready.extend(ready)
-                self._cv.notify_all()
+                self._enqueue_locked(ready)
             # count refires as sent while still holding the lock so the
             # termination detector never sees balanced counters with a
             # re-fire still pending (Mattern consistency)
@@ -455,6 +448,14 @@ class Scheduler:
                 wake.append(c)  # Waiter: events already in its frame
         if c.done:
             self._remove_consumer_locked(c)
+
+    def _enqueue_locked(self, ready: List[Instance]) -> None:
+        if self.trace_on:
+            now = time.monotonic_ns()
+            for inst in ready:
+                inst.ready_ns = now
+        self._ready.extend(ready)
+        self._cv.notify_all()
 
     def _remove_consumer_locked(self, c: Consumer) -> None:
         try:
@@ -549,10 +550,8 @@ class Scheduler:
                 if not c.done:
                     self._consumers.append(c)
                     self._router.register(c)
-            for inst in ready:
-                self._ready.append(inst)
             if ready:
-                self._cv.notify_all()
+                self._enqueue_locked(ready)
             self.sent += len(refires)
         for w in wake:
             with w.cv:
@@ -666,6 +665,7 @@ class Scheduler:
     # ----------------------------------------------------------------- locks
     def lock(self, name: str, blocking: bool = True) -> bool:
         me = threading.get_ident()
+        t_wait = 0          # traced runs: when this acquisition began to wait
         with self._mu:
             if self._locks.get(name) == me:
                 # reentrant acquisition: still record it so the lock is
@@ -676,10 +676,15 @@ class Scheduler:
             while self._locks.get(name) is not None:
                 if not blocking:
                     return False
+                if self.trace_on and not t_wait:
+                    t_wait = time.monotonic_ns()
                 self._lock_cv.wait()  # notified by unlock / shutdown
                 if self._shutdown:
                     return False
             self._locks[name] = me
+        if t_wait:
+            _trace.record_since(self._spans, "edat.lock_wait", t_wait,
+                                {"lock": name})
         if self._tls.locks is not None:
             self._tls.locks.add(name)
         return True
@@ -766,7 +771,8 @@ class Scheduler:
         self._tls.in_task = True
         # busy time is span-based (idle->busy transitions in _worker_loop),
         # so per-task timestamps are only taken for the opt-in trace
-        t0 = time.monotonic() if self.trace_on else 0.0
+        if self.trace_on:
+            sid, t0 = _trace.begin_task(self._spans)
         try:
             inst.fn(ctx, inst.events)
         except Exception as e:  # noqa: BLE001 - report any task failure
@@ -776,7 +782,9 @@ class Scheduler:
             for n in sorted(self._tls.locks):
                 self.unlock(n)  # auto-release (paper §IV.C)
             self._tls.locks = None
-            dur = (time.monotonic() - t0) if self.trace_on else 0.0
+            if self.trace_on:
+                _trace.end_task(self._spans, sid, t0, inst.name or getattr(
+                    inst.fn, "__name__", "?"), inst.ready_ns)
             with self._mu:
                 self._running -= 1
                 self._executed += 1
@@ -793,11 +801,6 @@ class Scheduler:
                                 rec = md[ev.eid] = [0, 0, 0, 0]
                             rec[1] += 1
                             rec[2] -= 1
-                if self.trace_on:
-                    self._trace_add_locked(
-                        ("task", t0, dur,
-                         inst.name or getattr(inst.fn, "__name__", "?"),
-                         len(inst.events)))
                 self._cv.notify_all()
                 idle = self._idle_locked()
             oc = self.on_consumed
@@ -829,12 +832,6 @@ class Scheduler:
             rec[1] += 1
             rec[2] -= 1
 
-    def _trace_add_locked(self, rec: tuple) -> None:
-        if len(self._trace) < TRACE_CAP:
-            self._trace.append(rec)
-        else:
-            self._trace_dropped += 1
-
     def metrics_snapshot(self) -> dict:
         """Consistent snapshot of this rank's counters (takes ``_mu``)."""
         with self._mu:
@@ -846,10 +843,9 @@ class Scheduler:
                 "tasks_executed": self._executed,
                 "busy_s": self._busy_s,
             }
-            if self.trace_on:
-                out["trace"] = list(self._trace)
-                out["trace_dropped"] = self._trace_dropped
-            return out
+        if self.trace_on:
+            out["trace"], out["trace_dropped"] = self._spans.snapshot()
+        return out
 
     # ---------------------------------------------------------- termination
     def set_main_done(self):

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import edat
 from repro.models import build_model
 from repro.train import make_prefill_step, make_serve_step
 
@@ -137,16 +138,24 @@ class ServeEngine:
         next-token column (``(slots,)``).  Tokens/positions advance only
         for ``live`` slots — dead rows keep stepping through the jitted
         batch (their output is ignored) but their position is pinned, so
-        an idle slot never walks its write pointer to ``max_len``."""
-        nxt, self.caches = self._decode(self.params, self.caches,
-                                        jnp.asarray(self.tokens),
-                                        jnp.asarray(self.pos))
-        self.step_count += 1
-        out = np.asarray(nxt)
-        for i in live:
-            self.tokens[i, 0] = out[i, 0]
-            self.pos[i, 0] += 1
-        return out[:, 0]
+        an idle slot never walks its write pointer to ``max_len``.
+
+        Traced, it records ``engine.step`` with two children:
+        ``engine.step.launch`` (the uploads of tokens and positions and the
+        jitted call returning) and ``engine.step.read`` (the token read,
+        which waits for the device)."""
+        with edat.span("engine.step"):
+            with edat.span("engine.step.launch"):
+                nxt, self.caches = self._decode(self.params, self.caches,
+                                                jnp.asarray(self.tokens),
+                                                jnp.asarray(self.pos))
+            self.step_count += 1
+            with edat.span("engine.step.read"):
+                out = np.asarray(nxt)
+            for i in live:
+                self.tokens[i, 0] = out[i, 0]
+                self.pos[i, 0] += 1
+            return out[:, 0]
 
 
 class SequentialEngine:

@@ -30,6 +30,12 @@ The server rank runs four persistent tasks:
   (responses to it would be dropped by the transport anyway), so the
   server drains cleanly under client SIGKILL.
 
+In a traced session (``Session(trace=True)``) the server records spans:
+``serve.request``, ``serve.prefill`` and ``serve.attach`` of one request
+share its id as ``req``; ``serve.step`` holds the ids of the live slots
+as ``live``; each ``server``-lock wait is an ``edat.lock_wait`` child of
+the span open when it began.
+
 Client ranks replay an open-loop :class:`~repro.serve.loadgen.LoadSpec`
 schedule and throttle while the server signals backpressure.  All
 latency accounting happens server-side from the ``t_sched`` stamps the
@@ -131,16 +137,18 @@ class ServeProgram:
             ctx.fire(rank, READY)
 
     def _on_request(self, ctx: edat.Context, events) -> None:
-        ctx.lock("server")
         ev = events[0]
-        if ev.source in self.dead:
-            return
-        req = dict(ev.data)
-        req["client"] = ev.source
-        req["t_recv"] = time.monotonic()
-        self.queue.append(req)
-        self._signal_backpressure(ctx)
-        self._pump(ctx)
+        with edat.span("serve.request", req=ev.data["id"]):
+            t_recv = time.monotonic()
+            ctx.lock("server")
+            if ev.source in self.dead:
+                return
+            req = dict(ev.data)
+            req["client"] = ev.source
+            req["t_recv"] = t_recv
+            self.queue.append(req)
+            self._signal_backpressure(ctx)
+            self._pump(ctx)
 
     def _pump(self, ctx: edat.Context) -> None:
         """Admission (server lock held): reserve a free slot per queued
@@ -164,9 +172,11 @@ class ServeProgram:
         t_admit = time.monotonic()
         # the expensive prompt-length-dependent phase, deliberately
         # outside the server lock: decode ticks keep running
-        first, pcache = eng.prefill(req["prompt"])
-        ctx.lock("server")
-        eng.attach(slot, len(req["prompt"]), first, pcache)
+        with edat.span("serve.prefill", req=req["id"]):
+            first, pcache = eng.prefill(req["prompt"])
+        with edat.span("serve.attach", req=req["id"]):
+            ctx.lock("server")
+            eng.attach(slot, len(req["prompt"]), first, pcache)
         rec = {"id": req["id"], "client": req["client"],
                "prompt_len": len(req["prompt"]), "tokens": [first],
                "left": max_new - 1,
@@ -195,7 +205,9 @@ class ServeProgram:
         if not live_idx:
             self._ticking = False
             return
-        out = self.engine.step(live_idx)
+        with edat.span("serve.step",
+                       live=tuple(self.live[i]["id"] for i in live_idx)):
+            out = self.engine.step(live_idx)
         now = time.monotonic()
         for i in live_idx:
             rec = self.live[i]

@@ -9,8 +9,12 @@ Covers the always-on observability layer end to end:
   ``channels`` / ``ranks`` / ``transport`` sections with exact counts
   for a deterministic program;
 * ``metrics=False`` really turns the structured sections off;
-* ``trace=True`` records bounded per-rank task/event timelines.
+* ``trace=True`` records bounded per-rank spans: task executions, lock
+  waits and the program's own ``edat.span`` calls.
 """
+import threading
+import time
+
 import numpy as np
 import pytest
 
@@ -122,23 +126,125 @@ def test_metrics_off_omits_structured_sections():
 
 
 def test_trace_records_task_and_recv_timelines():
+    """One ``edat.task`` span per execution, named by its task, ready
+    before it started; no per-delivery records."""
+    def main(ctx):
+        if ctx.rank == 0:
+            ctx.submit_persistent(lambda c, e: None, deps=[(1, "x")],
+                                  name="sink")
+        else:
+            for i in range(50):
+                ctx.fire(0, "x", i)
+
+    t_start = time.monotonic_ns()
     with edat.Session(2, trace=True) as s:
-        s.run(_fanout_main)
+        s.run(main)
         ranks = s.stats()["ranks"]
     trace0 = ranks[0]["trace"]
-    kinds = {rec[0] for rec in trace0}
-    assert kinds == {"recv", "task"}
-    tasks = [rec for rec in trace0 if rec[0] == "task"]
+    tasks = [rec for rec in trace0 if rec[5].get("task") == "sink"]
     assert len(tasks) == 50
-    # ("task", t0, dur, name, n_events) — timestamps are monotonic stamps
-    assert all(rec[2] >= 0.0 and rec[4] == 1 for rec in tasks)
-    assert ranks[0].get("trace_dropped", 0) == 0
+    assert {rec[0] for rec in trace0} == {"edat.task"}
+    # (name, t0_ns, t1_ns, span_id, parent_id, attrs) on monotonic_ns
+    for name, t0, t1, sid, parent, attrs in tasks:
+        assert t_start <= attrs["ready_ns"] <= t0 <= t1
+        assert sid > 0 and parent == 0
+    assert len({rec[3] for rec in trace0}) == len(trace0)
+    assert ranks[0]["trace_dropped"] == 0
 
 
 def test_trace_off_by_default():
     with edat.Session(2) as s:
         s.run(_fanout_main)
         assert "trace" not in s.stats()["ranks"][0]
+
+
+def _traced(main, ranks=1, **kw):
+    with edat.Session(ranks, trace=True, **kw) as s:
+        s.run(main)
+        st = s.stats()["ranks"]
+    return [rec for r in st for rec in st[r]["trace"]], st
+
+
+def test_spans_nest_under_the_running_task():
+    def main(ctx):
+        def task(c, e):
+            with edat.span("outer", k=1):
+                with edat.span("inner"):
+                    pass
+                with edat.span("sibling"):
+                    pass
+        ctx.submit(task, name="work")
+
+    spans, _ = _traced(main)
+    by = {rec[0]: rec for rec in spans}
+    assert set(by) == {"edat.task", "outer", "inner", "sibling"}
+    task, outer = by["edat.task"], by["outer"]
+    assert task[5]["task"] == "work" and task[4] == 0
+    assert outer[4] == task[3] and outer[5] == {"k": 1}
+    assert by["inner"][4] == outer[3] and by["sibling"][4] == outer[3]
+    for rec in (outer, by["inner"], by["sibling"]):
+        parent = {r[3]: r for r in spans}[rec[4]]
+        assert parent[1] <= rec[1] <= rec[2] <= parent[2]
+
+
+def test_lock_wait_span_only_when_contended():
+    """Two tasks on two workers take one named lock: the one that had to
+    wait records ``edat.lock_wait`` under its open span; the uncontended
+    acquisition records nothing."""
+    held = threading.Event()
+
+    def main(ctx):
+        def first(c, e):
+            c.lock("L")
+            held.set()
+            time.sleep(0.05)
+
+        def second(c, e):
+            held.wait(5)
+            with edat.span("work"):
+                c.lock("L")
+
+        ctx.submit(first, name="first")
+        ctx.submit(second, name="second")
+
+    spans, _ = _traced(main, workers_per_rank=2)
+    waits = [rec for rec in spans if rec[0] == "edat.lock_wait"]
+    assert len(waits) == 1
+    (name, t0, t1, sid, parent, attrs), = waits
+    assert attrs == {"lock": "L"} and t1 - t0 >= 10_000_000
+    work = [rec for rec in spans if rec[0] == "work"][0]
+    assert parent == work[3]
+
+
+def test_span_without_tracing_is_the_shared_noop():
+    from repro.core import trace
+    seen = []
+
+    def main(ctx):
+        def task(c, e):
+            seen.append(edat.span("x", a=1))
+            with seen[-1]:
+                c.lock("L")
+        ctx.submit(task)
+
+    with edat.Session(1) as s:
+        s.run(main)
+        assert "trace" not in s.stats()["ranks"][0]
+    assert seen == [trace.NO_SPAN]
+    assert edat.span("outside a task") is trace.NO_SPAN
+
+
+def test_trace_cap_counts_drops(monkeypatch):
+    from repro.core import trace
+    monkeypatch.setattr(trace, "TRACE_CAP", 7)
+
+    def main(ctx):
+        for _ in range(10):
+            ctx.submit(lambda c, e: None)
+
+    spans, ranks = _traced(main)
+    assert len(spans) == 7
+    assert ranks[0]["trace_dropped"] == 3
 
 
 # ---------------------------------------------------- socket session merge

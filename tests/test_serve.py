@@ -195,6 +195,44 @@ def test_backpressure_throttles_and_insights_flag_it():
     assert "admission-backpressure" in rules
 
 
+def test_traced_serving_records_request_and_step_spans():
+    """``Session(trace=True)``: every request has its ``serve.request``,
+    ``serve.prefill`` and ``serve.attach`` spans under one ``req``, each
+    under its task; one ``serve.step`` per engine step, each holding
+    ``engine.step`` with its launch and read."""
+    load = LoadSpec(rps=200.0, requests=6, prompt_lens=(4, 8),
+                    max_new_lo=3, max_new_hi=6, seed=4)
+    with edat.Session(3, workers_per_rank=2, unconsumed="ignore",
+                      trace=True, timeout=300) as s:
+        s.run(serve_program(arch=ARCH, slots=2, max_len=MAX_LEN,
+                            load=load))
+        res = s.gather()
+        ranks = s.stats()["ranks"]
+    assert all(rk["trace_dropped"] == 0 for rk in ranks.values())
+    spans = [rec for rk in ranks.values() for rec in rk["trace"]]
+    by_id = {rec[3]: rec for rec in spans}
+    ids = sorted(r["id"] for r in res["records"])
+    assert res["served"] == 6
+    for name, task in (("serve.request", "serve.request"),
+                       ("serve.prefill", "serve.prefill"),
+                       ("serve.attach", "serve.prefill")):
+        got = [rec for rec in spans if rec[0] == name]
+        assert sorted(rec[5]["req"] for rec in got) == ids
+        for rec in got:
+            parent = by_id[rec[4]]
+            assert parent[0] == "edat.task" and parent[5]["task"] == task
+    steps = [rec for rec in spans if rec[0] == "serve.step"]
+    assert len(steps) == res["steps"] > 0
+    for st in steps:
+        kids = [rec for rec in spans if rec[4] == st[3]]
+        assert [k[0] for k in kids] == ["engine.step"]
+        assert sorted(rec[0] for rec in spans if rec[4] == kids[0][3]) == [
+            "engine.step.launch", "engine.step.read"]
+    # each token after a request's first comes from a step it was live in
+    assert sum(len(st[5]["live"]) for st in steps) == sum(
+        r["n_out"] - 1 for r in res["records"])
+
+
 # ------------------------------------------------------------------- chaos
 def test_client_sigkill_drains_cleanly(tmp_path):
     """SIGKILL one of two client processes once the server has admitted
