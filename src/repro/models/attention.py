@@ -44,6 +44,31 @@ def ref_attention(q, k, v, *, scale, q_pos, k_pos, window: Optional[int],
     return out.reshape(B, Sq, H, v.shape[-1])  # v dim may differ (MLA)
 
 
+def write_cache(cache: dict, layer, slot, new: dict) -> Tuple[dict, dict]:
+    """Write ``new[name]`` (B, S', ...) over cache entries ``slot`` (B, S')
+    of each batch row; returns ``(written cache, this layer's view)``.
+
+    With ``layer`` None every leaf is one layer's (B, L, ...).  Otherwise
+    the leaves are stacked over layers, as a scanned segment carries them,
+    S' is 1, and each row's entry goes in place at ``(layer, row, slot)``
+    by its own ``dynamic_update_slice``: an update of a few entries that,
+    unlike a scatter, leaves the stack in the layout it is stored in.  The
+    view is the layer's slice, read once by attention."""
+    if layer is None:
+        bidx = jnp.arange(slot.shape[0])[:, None]
+        out = {n: cache[n].at[bidx, slot].set(v) for n, v in new.items()}
+        return out, out
+    out = {}
+    for n, c in cache.items():
+        v = new[n].astype(c.dtype)
+        for b in range(slot.shape[0]):
+            start = (layer, b, slot[b, 0]) + (0,) * (c.ndim - 3)
+            c = jax.lax.dynamic_update_slice(c, v[b][None, None], start)
+        out[n] = c
+    return out, {n: jax.lax.dynamic_index_in_dim(c, layer, keepdims=False)
+                 for n, c in out.items()}
+
+
 # =============================================================== GQA mixer
 def gqa_specs(cfg: ModelCfg) -> Dict[str, P]:
     d, H, KH, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd
@@ -65,11 +90,14 @@ def gqa_specs(cfg: ModelCfg) -> Dict[str, P]:
 
 
 def gqa_apply(p, x, *, cfg: ModelCfg, kind: str, positions,
-              cache: Optional[dict] = None) -> Tuple[jax.Array, Optional[dict]]:
+              cache: Optional[dict] = None,
+              layer=None) -> Tuple[jax.Array, Optional[dict]]:
     """kind: 'attn' (global) or 'local' (window=cfg.window).
 
     positions: (B, S) int32 absolute positions of x's tokens.
-    cache: {'k','v': (B, L, KH, D), 'pos': (B, L)} or None (training)."""
+    cache: {'k','v': (B, L, KH, D), 'pos': (B, L)} or None (training);
+    with ``layer`` given, every leaf is stacked over layers (a leading
+    layers dim) and this layer's entries are written in place."""
     B, S, _ = x.shape
     window = cfg.window if kind == "local" else None
     theta = cfg.local_rope_theta if kind == "local" else cfg.rope_theta
@@ -95,7 +123,7 @@ def gqa_apply(p, x, *, cfg: ModelCfg, kind: str, positions,
                                window=window, cfg=cfg,
                                causal=kind != "enc")
     else:
-        L = cache["k"].shape[1]
+        L = cache["k"].shape[-3]
         # ring-buffer slot for window caches; append slot for global caches.
         # If the update covers >= L tokens only the last L may be written
         # (duplicate-index scatter order is undefined otherwise).
@@ -103,14 +131,11 @@ def gqa_apply(p, x, *, cfg: ModelCfg, kind: str, positions,
             k_w, v_w, pos_w = k[:, -L:], v[:, -L:], positions[:, -L:]
         else:
             k_w, v_w, pos_w = k, v, positions
-        slot = pos_w % L                                       # (B, S')
-        bidx = jnp.arange(B)[:, None]
-        ck = cache["k"].at[bidx, slot].set(k_w)
-        cv = cache["v"].at[bidx, slot].set(v_w)
-        cpos = cache["pos"].at[bidx, slot].set(pos_w)
-        out = ref_attention(q, ck, cv, scale=scale, q_pos=positions,
-                            k_pos=cpos, window=window, cap=cfg.attn_softcap)
-        new_cache = {"k": ck, "v": cv, "pos": cpos}
+        new_cache, c = write_cache(cache, layer, pos_w % L,
+                                   {"k": k_w, "v": v_w, "pos": pos_w})
+        out = ref_attention(q, c["k"], c["v"], scale=scale, q_pos=positions,
+                            k_pos=c["pos"], window=window,
+                            cap=cfg.attn_softcap)
 
     out = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     if cfg.bias:
@@ -231,12 +256,14 @@ def mla_specs(cfg: ModelCfg) -> Dict[str, P]:
 
 
 def mla_apply(p, x, *, cfg: ModelCfg, positions,
-              cache: Optional[dict] = None) -> Tuple[jax.Array, Optional[dict]]:
+              cache: Optional[dict] = None,
+              layer=None) -> Tuple[jax.Array, Optional[dict]]:
     """DeepSeek-V3 Multi-head Latent Attention.
 
     Cache stores only the compressed latent (kv_lora) + shared rope key —
     the paper's memory saving.  Decode uses the absorbed formulation (no
-    materialised per-head K/V of length L)."""
+    materialised per-head K/V of length L).  ``layer``: as in
+    :func:`gqa_apply`."""
     m = cfg.mla
     B, S, _ = x.shape
     H = cfg.n_heads
@@ -265,12 +292,11 @@ def mla_apply(p, x, *, cfg: ModelCfg, positions,
                                window=None, cfg=cfg)
         new_cache = None
     else:
-        L = cache["c_kv"].shape[1]
-        bidx = jnp.arange(B)[:, None]
-        slot = positions % L
-        cc = cache["c_kv"].at[bidx, slot].set(c_kv)
-        cr = cache["k_rope"].at[bidx, slot].set(k_rope)
-        cpos = cache["pos"].at[bidx, slot].set(positions)
+        L = cache["c_kv"].shape[-2]
+        new_cache, c = write_cache(
+            cache, layer, positions % L,
+            {"c_kv": c_kv, "k_rope": k_rope, "pos": positions})
+        cc, cr, cpos = c["c_kv"], c["k_rope"], c["pos"]
         # absorbed: q_nope^T k_nope = (q_nope W_uk) . c_kv
         q_abs = jnp.einsum("bshk,lhk->bshl", q_nope, p["wk_b"])
         logits = (jnp.einsum("bshl,btl->bhst", q_abs, cc,
@@ -283,7 +309,6 @@ def mla_apply(p, x, *, cfg: ModelCfg, positions,
         probs = jax.nn.softmax(logits, axis=-1)
         ctx_l = jnp.einsum("bhst,btl->bshl", probs.astype(cc.dtype), cc)
         out = jnp.einsum("bshl,lhk->bshk", ctx_l, p["wv_b"])
-        new_cache = {"c_kv": cc, "k_rope": cr, "pos": cpos}
 
     out = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     return out, new_cache

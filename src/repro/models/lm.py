@@ -12,6 +12,8 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from . import attention as attn
 from . import mamba2 as m2
@@ -20,7 +22,7 @@ from .common import (P, abstract_tree, axes_tree, gelu, init_tree, layer_norm,
                      rms_norm, sinusoid_positions)
 from .config import ModelCfg
 from .moe import moe_apply, moe_specs
-from repro.sharding.ctx import constrain
+from repro.sharding.ctx import constrain, current as current_mesh
 
 Desc = Tuple[str, str]  # (mixer kind, mlp kind)
 
@@ -128,12 +130,34 @@ def _dense_ff_specs(cfg: ModelCfg, mlp_kind: str):
     return mlp_specs(cfg)
 
 
-def mixer_apply(kind: str, p, x, *, cfg, positions, cache):
+#: mixers whose decode writes one entry per row of a position-indexed
+#: cache; a scanned segment decoding one token carries their stacked caches
+#: and writes in place.  The others rewrite their whole state every step and
+#: keep the scan's xs->ys, as does a prefill, which writes whole prompts.
+STACK_WRITTEN = ("attn", "local", "mla")
+
+
+def stored_layouts(tree):
+    """The layout the default device stores each leaf of ``tree`` in (on a
+    TPU a stack of 64-wide heads is kept position-minor, unpadded).  A
+    carried stack pinned to it is copied once at entry as it is, where
+    XLA's own choice for the loop would relayout it there and back."""
+    dev = jax.config.jax_default_device
+    if not isinstance(dev, jax.Device):
+        dev = jax.devices(dev)[0]
+    return jax.tree.map(
+        lambda a: Layout(major_to_minor=Layout.from_pjrt_layout(
+            dev.client.get_default_layout(np.dtype(a.dtype), a.shape, dev)
+        ).major_to_minor), tree)
+
+
+def mixer_apply(kind: str, p, x, *, cfg, positions, cache, layer=None):
     if kind in ("attn", "local", "enc"):
         return attn.gqa_apply(p, x, cfg=cfg, kind=kind, positions=positions,
-                              cache=cache)
+                              cache=cache, layer=layer)
     if kind == "mla":
-        return attn.mla_apply(p, x, cfg=cfg, positions=positions, cache=cache)
+        return attn.mla_apply(p, x, cfg=cfg, positions=positions, cache=cache,
+                              layer=layer)
     if kind == "ssd":
         return m2.mamba2_apply(p, x, cfg=cfg, cache=cache)
     if kind == "rglru":
@@ -141,11 +165,15 @@ def mixer_apply(kind: str, p, x, *, cfg, positions, cache):
     raise ValueError(kind)
 
 
-def layer_apply(lp, x, *, cfg: ModelCfg, desc: Desc, positions, cache):
+def layer_apply(lp, x, *, cfg: ModelCfg, desc: Desc, positions, cache,
+                layer=None):
+    """``layer``: this layer's index when ``cache`` is the whole segment's
+    stack (see ``STACK_WRITTEN``), else None."""
     mixer, mlp_kind = desc
     h = norm_apply(lp["ln1"], x, cfg)
     mix, new_cache = mixer_apply(mixer, lp["mix"], h, cfg=cfg,
-                                 positions=positions, cache=cache)
+                                 positions=positions, cache=cache,
+                                 layer=layer)
     if cfg.post_norms:
         mix = norm_apply(lp["ln1p"], mix, cfg)
     x = x + mix
@@ -279,13 +307,18 @@ class TransformerLM:
         for si, (unit, reps) in enumerate(self.segments):
             seg_p = params[f"seg{si}"]
             seg_c = caches[si] if caches is not None else [None] * len(unit)
-            body = self._unit_body(unit, positions, caches is not None)
-            if cfg.remat != "none":
-                policy = (jax.checkpoint_policies.nothing_saveable
-                          if cfg.remat == "full" else
-                          jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-                body = jax.checkpoint(body, policy=policy,
-                                      prevent_cse=reps == 1)
+            # under a mesh (``use_sharding``) the batch may be split, and a
+            # row's write into a split stack would gather it: keep xs->ys
+            if (caches is not None and reps > 1 and x.shape[1] == 1
+                    and any(desc[0] in STACK_WRITTEN for desc in unit)
+                    and current_mesh() is None):
+                x, aux, ncs = self._scan_stacked(unit, positions, x, aux,
+                                                 seg_p, seg_c, reps)
+                new_caches.append(ncs)
+                continue
+            body = self._remat(self._unit_body(unit, positions,
+                                               caches is not None),
+                               prevent_cse=reps == 1)
             if reps == 1:
                 (x, aux), ncs = body((x, aux), (seg_p, seg_c))
                 new_caches.append(ncs)
@@ -294,6 +327,50 @@ class TransformerLM:
                 new_caches.append(ncs)
         x = norm_apply(params["final_norm"], x, cfg)
         return x, (new_caches if caches is not None else None), aux
+
+    def _remat(self, body, *, prevent_cse: bool):
+        cfg = self.cfg
+        if cfg.remat == "none":
+            return body
+        policy = (jax.checkpoint_policies.nothing_saveable
+                  if cfg.remat == "full" else
+                  jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+        return jax.checkpoint(body, policy=policy, prevent_cse=prevent_cse)
+
+    def _scan_stacked(self, unit, positions, x, aux, seg_p, seg_c, reps):
+        """One scanned segment decoding one token with caches.  The stacks
+        of ``STACK_WRITTEN`` units ride in the carry, held to the layout
+        the device stores them in: one copy of each at entry (the caller's
+        cache must stay intact), then each layer writes its new entries in
+        place at its index and attention reads its slice.  Every other
+        unit's cache scans as xs -> ys, rewritten whole by its layer."""
+        cfg = self.cfg
+        stacked = [desc[0] in STACK_WRITTEN for desc in unit]
+        stacks = [c if s else None for c, s in zip(seg_c, stacked)]
+        slices = [None if s else c for c, s in zip(seg_c, stacked)]
+        layouts = stored_layouts(stacks)
+
+        def body(carry, xs):
+            x, aux, stacks = carry
+            pslices, cslices, i = xs
+            stacks, ys = list(with_layout_constraint(stacks, layouts)), []
+            for ui, desc in enumerate(unit):
+                x, nc, a = layer_apply(
+                    pslices[f"u{ui}"], x, cfg=cfg, desc=desc,
+                    positions=positions,
+                    cache=stacks[ui] if stacked[ui] else cslices[ui],
+                    layer=i if stacked[ui] else None)
+                if stacked[ui]:
+                    stacks[ui] = nc
+                ys.append(None if stacked[ui] else nc)
+                aux = aux + a
+            return (x, aux, with_layout_constraint(stacks, layouts)), ys
+
+        (x, aux, stacks), ys = jax.lax.scan(
+            self._remat(body, prevent_cse=False), (x, aux, stacks),
+            (seg_p, slices, jnp.arange(reps, dtype=jnp.int32)))
+        return x, aux, [st if s else y
+                        for st, y, s in zip(stacks, ys, stacked)]
 
     def logits(self, params, hidden):
         cfg = self.cfg

@@ -1,4 +1,5 @@
-"""The Pallas kernels compile for a TPU v5e chip at real widths.
+"""The Pallas kernels, and the serving decode step's cache form, compile
+for a TPU v5e chip at real widths.
 
 Interpret mode (tests/test_kernels.py) checks the numbers but not what
 Mosaic accepts: block tiling, VMEM budget, lowerable ops.  These tests
@@ -10,6 +11,7 @@ it loads libtpu, which one process at a time may hold, so nothing here
 runs at import or collection time.
 """
 import os
+import re
 
 import pytest
 
@@ -19,6 +21,10 @@ import jax.numpy as jnp
 from repro.kernels.flash_attention import kernel as fa
 from repro.kernels.rglru import kernel as rg
 from repro.kernels.ssd import kernel as ssd
+from repro.configs import ARCHS
+from repro.models import build_model
+from repro.serve.engine import serving_cfg
+from repro.train import make_serve_step
 
 
 @pytest.fixture(scope="module")
@@ -87,3 +93,26 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
     fn, args = KERNELS[name](sds)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_decode_step_keeps_the_stored_cache_layout(topo, one_chip,
+                                                   no_persistent_cache):
+    """StableLM-2 widths, 4 layers, 16 slots x 1024: the chip stores the
+    stacked K/V position-minor (64-wide heads, unpadded).  The layer loop
+    carries them in that layout, so the only whole-stack copies are the
+    two at entry, and no layer's K/V slice is copied (relayout) at all."""
+    def sds(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    model = build_model(serving_cfg(
+        ARCHS["stablelm-1.6b"].cfg.replace(n_layers=4), 1024))
+    params = sds(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    caches = sds(model.abstract_cache(16, 1024))
+    tok = jax.ShapeDtypeStruct((16, 1), jnp.int32, sharding=one_chip)
+    with jax.default_device(topo.devices[0]):
+        text = jax.jit(make_serve_step(model)).lower(
+            params, caches, tok, tok).compile().as_text()
+    copies = re.findall(r"= bf16\[([\d,]+)\]\S* copy\(", text)
+    assert copies.count("4,16,1024,32,64") == 2, copies
+    assert not {"1,16,1024,32,64", "16,1024,32,64"} & set(copies), copies
