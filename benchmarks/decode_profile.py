@@ -3,16 +3,23 @@
 
     python3 benchmarks/decode_profile.py --arch stablelm-1.6b --slots 16 \\
         --max-len 1024 --steps 8 --out chiprun_out/decode_profile.json
+    python3 benchmarks/decode_profile.py \\
+        --config benchmarks/chip/configs/longcat-flash-chat.json \\
+        --slots 64 --max-len 2048 --pos 1024
 
 Builds the architecture at its published widths (``serving_cfg``: the
-engine's own), random weights made on the device from ``--seed``, a cache
+engine's own), or as a chip benchmark configuration file cuts it
+(``--config``, read as the benchmark's harness reads it), random
+weights made on the device from ``--seed``, a cache
 of ``--slots`` rows of ``--max-len`` with every slot at ``--pos``, and
 ``jax.jit(make_serve_step(model))`` as ``ServeEngine`` jits it.  After two
 warm calls it traces ``--steps`` steps with the JAX profiler, each ended
 by ``block_until_ready``, and reduces the device plane's ``XLA Ops`` line
 to the time per step of each operation, with its opcode, result shape and
 the bytes of that result (unpadded) from the compiled HLO.  Prints the top
-operations; writes them all to ``--out`` as JSON.  Needs a TPU: on any other platform it
+operations and the time per step under each named scope of ``SCOPES``
+(the first that an operation's ``op_name`` holds); writes them all to
+``--out`` as JSON.  Needs a TPU: on any other platform it
 exits with code 2.
 """
 from __future__ import annotations
@@ -28,11 +35,15 @@ import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "src"))
+sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
 
 OPS, MODULES = "XLA Ops", "XLA Modules"
 STEP = "serve_step"
 NESTING = ("while", "conditional", "call")
+#: named scopes of the model's layers, most specific first
+SCOPES = ("moe.router", "moe.experts", "moe.zero", "mla", "mlp")
 _INST = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\S+?)(?:\{[^}]*\})? ([\w-]+)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
 _NEST = re.compile(r"^\s*(?:ROOT )?%(\S+) = \(.*\) (while|conditional|call)\(")
 
 
@@ -48,16 +59,26 @@ def shape_bytes(shape: str) -> int:
     return n * int(m.group(2)) // 8
 
 
+def scope_of(op_name: str) -> str:
+    """The first of ``SCOPES`` on the path ``op_name``, else ``other``."""
+    parts = op_name.split("/")
+    return next((s for s in SCOPES if s in parts), "other")
+
+
 def hlo_ops(text: str) -> dict:
-    """``{instruction name: (result shape, opcode)}`` from HLO text."""
+    """``{instruction name: (result shape, opcode, scope)}`` from HLO
+    text (a nested computation's scope is ``other``)."""
     out = {}
     for line in text.splitlines():
         m = _INST.match(line)
         if m:
-            out[m.group(1)] = (m.group(2), m.group(3))
+            name = _OP_NAME.search(line)
+            out[m.group(1)] = (m.group(2), m.group(3),
+                               scope_of(name.group(1) if name else ""))
         elif _NEST.match(line):
             out[_NEST.match(line).group(1)] = ("(tuple)",
-                                               _NEST.match(line).group(2))
+                                               _NEST.match(line).group(2),
+                                               "other")
     return out
 
 
@@ -94,6 +115,10 @@ def reduce_ops(trace_dir: str, steps: int) -> tuple:
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--arch", default="stablelm-1.6b")
+    ap.add_argument("--config", default="",
+                    help="a chip benchmark configuration file (it names "
+                         "its architecture and cuts it; --arch is then "
+                         "not read)")
     ap.add_argument("--slots", type=int, default=16)
     ap.add_argument("--max-len", type=int, default=1024)
     ap.add_argument("--pos", type=int, default=512)
@@ -116,7 +141,13 @@ def main() -> int:
     from repro.train import make_serve_step
     compile_cache.enable()
 
-    model = build_model(serving_cfg(ARCHS[args.arch].cfg, args.max_len))
+    if args.config:
+        from chip.harness import program_cfg
+        with open(args.config) as f:
+            cfg = program_cfg(json.load(f))
+    else:
+        cfg = ARCHS[args.arch].cfg
+    model = build_model(serving_cfg(cfg, args.max_len))
     params = jax.jit(model.init)(jax.random.PRNGKey(args.seed % 2**31))
     caches = model.init_cache(args.slots, args.max_len)
     tokens = jnp.zeros((args.slots, 1), jnp.int32)
@@ -125,7 +156,7 @@ def main() -> int:
     compiled = step.lower(params, caches, tokens, pos).compile()
     shapes = hlo_ops(compiled.as_text())
     for _ in range(2):
-        tokens, caches = step(params, caches, tokens, pos)
+        tokens, caches, _ = step(params, caches, tokens, pos)
     jax.block_until_ready(caches)
 
     host = []
@@ -133,7 +164,7 @@ def main() -> int:
         with jax.profiler.trace(trace_dir):
             for _ in range(args.steps):
                 t0 = time.perf_counter()
-                tokens, caches = step(params, caches, tokens, pos)
+                tokens, caches, _ = step(params, caches, tokens, pos)
                 jax.block_until_ready((tokens, caches))
                 host.append((time.perf_counter() - t0) * 1e3)
         ops, module_ms = reduce_ops(trace_dir, args.steps)
@@ -141,16 +172,23 @@ def main() -> int:
         print("no XLA Ops events on /device:TPU:0", file=sys.stderr)
         return 1
     for name, op in ops.items():
-        op["shape"], op["opcode"] = shapes.get(name, ("", ""))
+        op["shape"], op["opcode"], op["scope"] = shapes.get(name,
+                                                           ("", "", "other"))
         op["result_bytes"] = shape_bytes(op["shape"])
+    scopes = {s: 0.0 for s in SCOPES + ("other",)}
+    for op in ops.values():
+        if op["opcode"] not in NESTING:
+            scopes[op["scope"]] += op["ms_per_step"]
     # a loop's own event spans the operations of its body
     total = sum(op["ms_per_step"] for op in ops.values()
                 if op["opcode"] not in NESTING)
     top = sorted(ops.items(), key=lambda kv: -kv[1]["ms_per_step"])
-    print(f"{args.arch} slots={args.slots} max_len={args.max_len} "
+    print(f"{cfg.name} slots={args.slots} max_len={args.max_len} "
           f"pos={args.pos} device={dev.device_kind}: step {module_ms:.3f} ms "
           f"on the device (ops {total:.3f} ms), host "
           f"{sorted(host)[len(host) // 2]:.3f} ms median")
+    print("by scope: " + ", ".join(f"{s} {ms:.3f} ms"
+                                   for s, ms in scopes.items()))
     for name, op in top[:args.top]:
         print(f"{op['ms_per_step']:9.3f} ms {100 * op['ms_per_step'] / total:5.1f}%"
               f" x{op['per_step']:<6g} {op['opcode']:<22} {name:<40} "
@@ -158,10 +196,11 @@ def main() -> int:
     if args.out:
         os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
         with open(args.out, "w") as f:
-            json.dump({"arch": args.arch, "slots": args.slots,
+            json.dump({"arch": cfg.name, "slots": args.slots,
                        "max_len": args.max_len, "pos": args.pos,
                        "device": dev.device_kind, "module_ms": module_ms,
                        "ops_ms": total, "host_ms": host,
+                       "scopes_ms": scopes,
                        "ops": dict(top)}, f, indent=1)
     return 0
 

@@ -46,10 +46,11 @@ def reduce_cfg(cfg: ModelCfg) -> ModelCfg:
             n_shared=cfg.moe.n_shared,
             first_dense=min(cfg.moe.first_dense, 1),
             d_ff_dense=128, router_scale=cfg.moe.router_scale,
-            capacity_factor=8.0)
+            capacity_factor=8.0, n_zero=4 if cfg.moe.n_zero else 0,
+            routed_scale=cfg.moe.routed_scale)
     if cfg.mla is not None:
         kw["mla"] = MLACfg(q_lora=64, kv_lora=32, rope_dim=16, nope_dim=32,
-                           v_dim=32)
+                           v_dim=32, lora_scale=cfg.mla.lora_scale)
     if cfg.ssm is not None:
         kw["ssm"] = SSMCfg(d_state=16, d_conv=4, expand=2, head_dim=16,
                            n_groups=1, chunk=32)

@@ -83,6 +83,10 @@ class Span:
         self.t0 = time.monotonic_ns()
         return self
 
+    def set(self, **attrs: Any) -> None:
+        """Add ``attrs`` to the span's record."""
+        self.attrs.update(attrs)
+
     def __exit__(self, *exc) -> bool:
         t1 = time.monotonic_ns()
         _here.cur = self.parent
@@ -96,6 +100,9 @@ class _NoSpan:
 
     def __enter__(self) -> "_NoSpan":
         return self
+
+    def set(self, **attrs: Any) -> None:
+        pass
 
     def __exit__(self, *exc) -> bool:
         return False

@@ -209,7 +209,7 @@ def build_cell(arch: str, shape_name: str, mesh: Mesh, *,
     return Cell(arch, shape_name, fn, (params_abs, cache_abs, tok_abs,
                                        pos_abs),
                 (p_shard, c_shard, t_shard, t_shard),
-                (t_shard, c_shard), mesh, rules, meta,
+                (t_shard, c_shard, None), mesh, rules, meta,
                 donate=(1,))                             # KV caches in-place
 
 

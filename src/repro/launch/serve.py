@@ -48,7 +48,7 @@ def main(argv=None):
     out = [nxt]
     t0 = time.monotonic()
     for i in range(args.max_new - 1):
-        nxt, caches = serve_step(params, caches, nxt, pos)
+        nxt, caches, _ = serve_step(params, caches, nxt, pos)
         pos = pos + 1
         out.append(nxt)
     dt = time.monotonic() - t0

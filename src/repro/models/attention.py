@@ -263,7 +263,9 @@ def mla_apply(p, x, *, cfg: ModelCfg, positions,
     Cache stores only the compressed latent (kv_lora) + shared rope key —
     the paper's memory saving.  Decode uses the absorbed formulation (no
     materialised per-head K/V of length L).  ``layer``: as in
-    :func:`gqa_apply`."""
+    :func:`gqa_apply`.  ``cfg.mla.lora_scale`` (LongCat-Flash) scales q by
+    ``(d_model / q_lora) ** 0.5`` and the normed latent by
+    ``(d_model / kv_lora) ** 0.5``; the latent norms keep eps 1e-6."""
     m = cfg.mla
     B, S, _ = x.shape
     H = cfg.n_heads
@@ -272,11 +274,16 @@ def mla_apply(p, x, *, cfg: ModelCfg, positions,
     q = jnp.einsum("bsd,dl->bsl", x, p["wq_a"])
     q = rms_norm(q, p["q_norm"])
     q = jnp.einsum("bsl,lhk->bshk", q, p["wq_b"])       # (B,S,H,nope+rope)
+    if m.lora_scale:
+        q = q * jnp.asarray((cfg.d_model / m.q_lora) ** 0.5, q.dtype)
     q_nope, q_rope = q[..., :m.nope_dim], q[..., m.nope_dim:]
     q_rope = rotary(q_rope, positions, theta=cfg.rope_theta)
 
     c_kv = jnp.einsum("bsd,dl->bsl", x, p["wkv_a"])
     c_kv = rms_norm(c_kv, p["kv_norm"])
+    if m.lora_scale:   # the cache holds the scaled latent
+        c_kv = c_kv * jnp.asarray((cfg.d_model / m.kv_lora) ** 0.5,
+                                  c_kv.dtype)
     k_rope = jnp.einsum("bsd,dr->bsr", x, p["wk_rope"])
     k_rope = rotary(k_rope[:, :, None, :], positions,
                     theta=cfg.rope_theta)[:, :, 0, :]

@@ -15,6 +15,10 @@ class MoECfg:
     d_ff_dense: int = 0          # d_ff of those dense layers
     capacity_factor: float = 1.25
     router_scale: bool = False   # normalise top-k weights (DeepSeek sigmoid)
+    # LongCat-Flash: the router scores n_experts routed plus n_zero
+    # identity experts (ModelCfg.held_experts: the share held here)
+    n_zero: int = 0
+    routed_scale: float = 1.0    # chosen scores times this, no renorm
 
 
 @dataclasses.dataclass(frozen=True)
@@ -24,6 +28,9 @@ class MLACfg:
     rope_dim: int = 64
     nope_dim: int = 128
     v_dim: int = 128
+    # LongCat-Flash: q times (d_model/q_lora)^0.5, normed latent times
+    # (d_model/kv_lora)^0.5
+    lora_scale: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -59,7 +66,8 @@ class ModelCfg:
     head_dim: int = 0            # 0 -> d_model // n_heads
 
     # layer mixing pattern: repeating unit of
-    #   'attn' (global), 'local' (sliding window), 'mla', 'ssd', 'rglru'
+    #   'attn' (global), 'local' (sliding window), 'mla', 'ssd', 'rglru',
+    #   'longcat' (two MLA sublayers, two MLPs and a shortcut held-share MoE)
     pattern: Tuple[str, ...] = ("attn",)
     window: int = 4096           # sliding-window size for 'local'
     local_rope_theta: float = 10000.0
@@ -76,6 +84,7 @@ class ModelCfg:
 
     # norms / mlp
     norm: str = "rmsnorm"        # rmsnorm | layernorm
+    norm_eps: Optional[float] = None   # None: 1e-6 rmsnorm, 1e-5 layernorm
     norm_plus_one: bool = False  # Gemma-family (1+w) RMSNorm
     post_norms: bool = False     # Gemma-2/3 post-attn/post-mlp norms
     mlp: str = "gated_silu"      # gated_silu | gelu | gated_gelu
@@ -97,6 +106,12 @@ class ModelCfg:
     n_frontend_tokens: int = 0
 
     mtp_depth: int = 0           # DeepSeek-V3 multi-token prediction
+
+    # the chip's share of an expert-parallel deployment (held-share expert
+    # layers): routed experts held_start .. held_start + held_experts - 1
+    # live here; 0 holds every one
+    held_experts: int = 0
+    held_start: int = 0
 
     # compute knobs (not architecture): may be overridden per experiment
     dtype: str = "bfloat16"

@@ -199,3 +199,7 @@ class EncDecLM:
         lg, caches, _ = self.decode(params, tokens, None, positions=pos,
                                     caches=caches, cross_kv=cross_kv)
         return lg, (caches, cross_kv)
+
+    def decode_counted(self, params, state, tokens, pos):
+        """:meth:`decode_step`; no layer routes to experts (None)."""
+        return (*self.decode_step(params, state, tokens, pos), None)

@@ -21,7 +21,7 @@ from . import rglru as rg
 from .common import (P, abstract_tree, axes_tree, gelu, init_tree, layer_norm,
                      rms_norm, sinusoid_positions)
 from .config import ModelCfg
-from .moe import moe_apply, moe_specs
+from .moe import held_moe_apply, held_moe_specs, moe_apply, moe_specs
 from repro.sharding.ctx import constrain, current as current_mesh
 
 Desc = Tuple[str, str]  # (mixer kind, mlp kind)
@@ -61,8 +61,10 @@ def norm_specs(cfg: ModelCfg) -> Dict[str, P]:
 
 def norm_apply(p, x, cfg: ModelCfg):
     if cfg.norm == "layernorm":
-        return layer_norm(x, p["w"], p["b"])
-    return rms_norm(x, p["w"], plus_one=cfg.norm_plus_one)
+        return layer_norm(x, p["w"], p["b"],
+                          eps=1e-5 if cfg.norm_eps is None else cfg.norm_eps)
+    return rms_norm(x, p["w"], plus_one=cfg.norm_plus_one,
+                    eps=1e-6 if cfg.norm_eps is None else cfg.norm_eps)
 
 
 def mlp_specs(cfg: ModelCfg) -> Dict[str, P]:
@@ -108,6 +110,8 @@ MIXER_SPECS = {
 
 def layer_specs(cfg: ModelCfg, desc: Desc) -> Dict[str, Any]:
     mixer, mlp_kind = desc
+    if mixer == "longcat":
+        return longcat_specs(cfg)
     sp: Dict[str, Any] = {
         "ln1": norm_specs(cfg),
         "mix": MIXER_SPECS[mixer](cfg),
@@ -134,7 +138,13 @@ def _dense_ff_specs(cfg: ModelCfg, mlp_kind: str):
 #: cache; a scanned segment decoding one token carries their stacked caches
 #: and writes in place.  The others rewrite their whole state every step and
 #: keep the scan's xs->ys, as does a prefill, which writes whole prompts.
-STACK_WRITTEN = ("attn", "local", "mla")
+STACK_WRITTEN = ("attn", "local", "mla", "longcat")
+
+
+def no_rows(x):
+    """A layer's ``rows`` where it routes to no held expert: int32
+    ``(batch, 2)`` zeros (see ``layer_apply``)."""
+    return jnp.zeros((x.shape[0], 2), jnp.int32)
 
 
 def stored_layouts(tree):
@@ -167,9 +177,20 @@ def mixer_apply(kind: str, p, x, *, cfg, positions, cache, layer=None):
 
 def layer_apply(lp, x, *, cfg: ModelCfg, desc: Desc, positions, cache,
                 layer=None):
-    """``layer``: this layer's index when ``cache`` is the whole segment's
-    stack (see ``STACK_WRITTEN``), else None."""
+    """Returns ``(x, new_cache, aux, rows)``: ``aux`` the load-balance
+    loss, ``rows`` int32 ``(batch, 2)``, each batch row's (token, expert)
+    choices that fell on held and on identity experts in a held-share
+    expert layer (zeros for the others).  ``layer``: this layer's index when
+    ``cache`` is the whole segment's stack (see ``STACK_WRITTEN``), else
+    None."""
     mixer, mlp_kind = desc
+    aux = jnp.zeros((), jnp.float32)
+    if mixer == "longcat":
+        x, new_cache, rows = longcat_apply(lp, x, cfg=cfg,
+                                           positions=positions, cache=cache,
+                                           layer=layer)
+        return x, new_cache, aux, rows
+    rows = no_rows(x)
     h = norm_apply(lp["ln1"], x, cfg)
     mix, new_cache = mixer_apply(mixer, lp["mix"], h, cfg=cfg,
                                  positions=positions, cache=cache,
@@ -178,9 +199,8 @@ def layer_apply(lp, x, *, cfg: ModelCfg, desc: Desc, positions, cache,
         mix = norm_apply(lp["ln1p"], mix, cfg)
     x = x + mix
     x = constrain(x, ("batch", "residual_seq", "embed"))
-    aux = jnp.zeros((), jnp.float32)
     if mlp_kind == "none":
-        return x, new_cache, aux
+        return x, new_cache, aux, rows
     h = norm_apply(lp["ln2"], x, cfg)
     if mlp_kind == "moe":
         out, aux = moe_apply(lp["mlp"], h, cfg=cfg)
@@ -192,7 +212,50 @@ def layer_apply(lp, x, *, cfg: ModelCfg, desc: Desc, positions, cache,
         out = norm_apply(lp["ln2p"], out, cfg)
     x = x + out
     return (constrain(x, ("batch", "residual_seq", "embed")),
-            new_cache, aux)
+            new_cache, aux, rows)
+
+
+# ------------------------------------------- LongCat-Flash's double layer
+def longcat_specs(cfg: ModelCfg) -> Dict[str, Any]:
+    sp: Dict[str, Any] = {}
+    for i in (0, 1):
+        sp[f"ln_attn{i}"] = norm_specs(cfg)
+        sp[f"mla{i}"] = attn.mla_specs(cfg)
+        sp[f"ln_mlp{i}"] = norm_specs(cfg)
+        sp[f"mlp{i}"] = mlp_specs(cfg)
+    sp["moe"] = held_moe_specs(cfg)
+    return sp
+
+
+def longcat_apply(lp, x, *, cfg: ModelCfg, positions, cache, layer=None):
+    """LongCat-Flash's layer: two MLA sublayers, each followed by a dense
+    MLP, and a shortcut MoE fed from the first sublayer's MLP input and
+    added after the second::
+
+        h0 = x + MLA_0(norm(x));   u = norm(h0);   h1 = h0 + MLP_0(u)
+        h2 = h1 + MLA_1(norm(h1)); y = h2 + MLP_1(norm(h2)) + MoE(u)
+
+    ``cache``: ``{"mla0", "mla1"}`` latent caches (stacked over layers
+    when ``layer`` is given).  Returns ``(y, new_cache, rows)``."""
+    new_cache = None if cache is None else {}
+    shortcut = rows = None
+    for i in (0, 1):
+        with jax.named_scope("mla"):
+            h = norm_apply(lp[f"ln_attn{i}"], x, cfg)
+            mix, nc = attn.mla_apply(
+                lp[f"mla{i}"], h, cfg=cfg, positions=positions,
+                cache=None if cache is None else cache[f"mla{i}"],
+                layer=layer)
+        if cache is not None:
+            new_cache[f"mla{i}"] = nc
+        x = x + mix
+        u = norm_apply(lp[f"ln_mlp{i}"], x, cfg)
+        if i == 0:
+            with jax.named_scope("moe"):
+                shortcut, rows = held_moe_apply(lp["moe"], u, cfg=cfg)
+        with jax.named_scope("mlp"):
+            x = x + mlp_apply(lp[f"mlp{i}"], u, cfg)
+    return x + shortcut, new_cache, rows
 
 
 def mixer_cache_spec(cfg: ModelCfg, kind: str, batch: int, max_len: int):
@@ -202,6 +265,9 @@ def mixer_cache_spec(cfg: ModelCfg, kind: str, batch: int, max_len: int):
         return attn.gqa_cache_spec(cfg, "local", batch, max_len)
     if kind == "mla":
         return attn.mla_cache_spec(cfg, batch, max_len)
+    if kind == "longcat":
+        return {f"mla{i}": attn.mla_cache_spec(cfg, batch, max_len)
+                for i in (0, 1)}
     if kind == "ssd":
         return m2.mamba2_cache_spec(cfg, batch)
     if kind == "rglru":
@@ -217,6 +283,8 @@ class TransformerLM:
         self.cfg = cfg
         self.descs = self._descs()
         self.segments = build_segments(self.descs)
+        #: whether a layer routes to held experts (decode counts its rows)
+        self.routes = any(d[0] == "longcat" for d in self.descs)
 
     def _descs(self) -> List[Desc]:
         cfg = self.cfg
@@ -283,17 +351,17 @@ class TransformerLM:
     def _unit_body(self, unit, positions, cache_mode):
         cfg = self.cfg
 
-        def body(x_aux, slices):
-            x, aux = x_aux
+        def body(carry, slices):
+            x, aux, rows = carry
             pslices, cslices = slices
             new_caches = []
             for ui, desc in enumerate(unit):
-                x, nc, a = layer_apply(
+                x, nc, a, r = layer_apply(
                     pslices[f"u{ui}"], x, cfg=cfg, desc=desc,
                     positions=positions, cache=cslices[ui])
                 new_caches.append(nc)
-                aux = aux + a
-            return (x, aux), new_caches
+                aux, rows = aux + a, rows + r
+            return (x, aux, rows), new_caches
         return body
 
     def forward(self, params, x, *, positions, caches=None):
@@ -301,8 +369,15 @@ class TransformerLM:
 
         caches: list per segment of per-unit cache trees (stacked when the
         segment is scanned), or None for training."""
+        return self.forward_counted(params, x, positions=positions,
+                                    caches=caches)[:3]
+
+    def forward_counted(self, params, x, *, positions, caches=None):
+        """:meth:`forward`, and the expert choices summed over layers
+        (``layer_apply``'s ``rows``)."""
         cfg = self.cfg
         aux = jnp.zeros((), jnp.float32)
+        rows = no_rows(x)
         new_caches = []
         for si, (unit, reps) in enumerate(self.segments):
             seg_p = params[f"seg{si}"]
@@ -312,21 +387,22 @@ class TransformerLM:
             if (caches is not None and reps > 1 and x.shape[1] == 1
                     and any(desc[0] in STACK_WRITTEN for desc in unit)
                     and current_mesh() is None):
-                x, aux, ncs = self._scan_stacked(unit, positions, x, aux,
-                                                 seg_p, seg_c, reps)
+                x, aux, rows, ncs = self._scan_stacked(
+                    unit, positions, (x, aux, rows), seg_p, seg_c, reps)
                 new_caches.append(ncs)
                 continue
             body = self._remat(self._unit_body(unit, positions,
                                                caches is not None),
                                prevent_cse=reps == 1)
             if reps == 1:
-                (x, aux), ncs = body((x, aux), (seg_p, seg_c))
+                (x, aux, rows), ncs = body((x, aux, rows), (seg_p, seg_c))
                 new_caches.append(ncs)
             else:
-                (x, aux), ncs = jax.lax.scan(body, (x, aux), (seg_p, seg_c))
+                (x, aux, rows), ncs = jax.lax.scan(body, (x, aux, rows),
+                                                   (seg_p, seg_c))
                 new_caches.append(ncs)
         x = norm_apply(params["final_norm"], x, cfg)
-        return x, (new_caches if caches is not None else None), aux
+        return x, (new_caches if caches is not None else None), aux, rows
 
     def _remat(self, body, *, prevent_cse: bool):
         cfg = self.cfg
@@ -337,7 +413,7 @@ class TransformerLM:
                   jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
         return jax.checkpoint(body, policy=policy, prevent_cse=prevent_cse)
 
-    def _scan_stacked(self, unit, positions, x, aux, seg_p, seg_c, reps):
+    def _scan_stacked(self, unit, positions, carry, seg_p, seg_c, reps):
         """One scanned segment decoding one token with caches.  The stacks
         of ``STACK_WRITTEN`` units ride in the carry, held to the layout
         the device stores them in: one copy of each at entry (the caller's
@@ -351,11 +427,11 @@ class TransformerLM:
         layouts = stored_layouts(stacks)
 
         def body(carry, xs):
-            x, aux, stacks = carry
+            x, aux, rows, stacks = carry
             pslices, cslices, i = xs
             stacks, ys = list(with_layout_constraint(stacks, layouts)), []
             for ui, desc in enumerate(unit):
-                x, nc, a = layer_apply(
+                x, nc, a, r = layer_apply(
                     pslices[f"u{ui}"], x, cfg=cfg, desc=desc,
                     positions=positions,
                     cache=stacks[ui] if stacked[ui] else cslices[ui],
@@ -363,14 +439,15 @@ class TransformerLM:
                 if stacked[ui]:
                     stacks[ui] = nc
                 ys.append(None if stacked[ui] else nc)
-                aux = aux + a
-            return (x, aux, with_layout_constraint(stacks, layouts)), ys
+                aux, rows = aux + a, rows + r
+            return (x, aux, rows,
+                    with_layout_constraint(stacks, layouts)), ys
 
-        (x, aux, stacks), ys = jax.lax.scan(
-            self._remat(body, prevent_cse=False), (x, aux, stacks),
+        (x, aux, rows, stacks), ys = jax.lax.scan(
+            self._remat(body, prevent_cse=False), (*carry, stacks),
             (seg_p, slices, jnp.arange(reps, dtype=jnp.int32)))
-        return x, aux, [st if s else y
-                        for st, y, s in zip(stacks, ys, stacked)]
+        return x, aux, rows, [st if s else y
+                              for st, y, s in zip(stacks, ys, stacked)]
 
     def logits(self, params, hidden):
         cfg = self.cfg
@@ -419,7 +496,7 @@ class TransformerLM:
         x = jnp.concatenate([h_in, e_in], axis=-1) @ mp["proj"]
         positions = jnp.broadcast_to(
             jnp.arange(x.shape[1], dtype=jnp.int32)[None], x.shape[:2])
-        x2, _, _aux = _single_layer(self, mp["layer"], x, positions)
+        x2, *_ = _single_layer(self, mp["layer"], x, positions)
         lg = self.logits(params, norm_apply(params["final_norm"], x2, cfg))
         return _xent(lg[:, :-1], labels[:, 2:] if labels.shape[1] > 2
                      else labels[:, :0])
@@ -442,14 +519,15 @@ class TransformerLM:
         cache = jax.tree.map(
             lambda s: jnp.zeros(s.shape, s.dtype or _dt(self.cfg)), specs,
             is_leaf=lambda x: isinstance(x, P))
-        # mark attention cache slots empty (pos = -1)
-        def fix(seg):
-            return [
-                (dict(u, pos=jnp.full_like(u["pos"], -1))
-                 if isinstance(u, dict) and "pos" in u else u)
-                for u in seg
-            ]
-        return [fix(seg) for seg in cache]
+        # mark attention cache slots empty (pos = -1), in nested units too
+        def fix(u):
+            if not isinstance(u, dict):
+                return u
+            u = {k: fix(v) for k, v in u.items()}
+            if "pos" in u:
+                u["pos"] = jnp.full_like(u["pos"], -1)
+            return u
+        return [[fix(u) for u in seg] for seg in cache]
 
     def abstract_cache(self, batch: int, max_len: int):
         return jax.tree.map(
@@ -471,9 +549,15 @@ class TransformerLM:
 
     def decode_step(self, params, caches, tokens, pos):
         """One decode step.  tokens: (B,1); pos: (B,1) absolute positions."""
+        return self.decode_counted(params, caches, tokens, pos)[:2]
+
+    def decode_counted(self, params, caches, tokens, pos):
+        """:meth:`decode_step`, and each row's expert choices summed over
+        layers (``layer_apply``'s ``rows``; None where no layer routes)."""
         x = self.embed(params, tokens)
-        h, caches, _ = self.forward(params, x, positions=pos, caches=caches)
-        return self.logits(params, h), caches
+        h, caches, _, rows = self.forward_counted(params, x, positions=pos,
+                                                  caches=caches)
+        return self.logits(params, h), caches, rows if self.routes else None
 
 
 def _single_layer(model: "TransformerLM", lp, x, positions):

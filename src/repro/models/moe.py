@@ -101,3 +101,66 @@ def moe_apply(p, x, *, cfg: ModelCfg) -> Tuple[jax.Array, jax.Array]:
         sh = jax.nn.silu(xf @ p["shared_wg"]) * (xf @ p["shared_wu"])
         out = out + sh @ p["shared_wd"]
     return out.reshape(B, S, d), aux
+
+
+# ------------------------------------------------------ held-share experts
+def held_moe_specs(cfg: ModelCfg) -> Dict[str, P]:
+    """The router over every routed and identity expert, and the held
+    routed experts' weights (``cfg.held_experts`` of ``n_experts``)."""
+    m = cfg.moe
+    d, f = cfg.d_model, m.d_expert
+    n = cfg.held_experts or m.n_experts
+    E = m.n_experts + m.n_zero
+    return {
+        "router": P((d, E), ("embed", None), scale=d ** -0.5),
+        "router_bias": P((E,), (None,), "zeros"),
+        "wg": P((n, d, f), ("expert", "embed", "moe_mlp")),
+        "wu": P((n, d, f), ("expert", "embed", "moe_mlp")),
+        "wd": P((n, f, d), ("expert", "moe_mlp", "embed")),
+    }
+
+
+def held_moe_apply(p, x, *, cfg: ModelCfg) -> Tuple[jax.Array, jax.Array]:
+    """LongCat-Flash's expert layer, for the routed experts this chip
+    holds (``cfg.held_start`` .. ``cfg.held_start + cfg.held_experts - 1``).
+
+    The float32 router scores all ``n_experts + n_zero`` experts with a
+    softmax, chooses the top ``top_k`` of score plus ``router_bias``, and
+    weighs each chosen expert by its score times ``routed_scale`` (no
+    renormalisation).  The result is the held experts' part for the rows
+    routed to them plus the identity experts' part (each returns its
+    input), which every chip computes alike.  No row is dropped: every
+    held expert runs over every row, weighted 0 where it was not chosen.
+
+    Returns ``(out, rows)``: ``rows`` int32 ``(B, 2)``, each batch row's
+    (token, expert) choices that fell on the held experts and on the
+    identity experts."""
+    m = cfg.moe
+    B, S, d = x.shape
+    xf = x.reshape(B * S, d)
+    n = cfg.held_experts or m.n_experts
+    with jax.named_scope("moe.router"):
+        logits = jnp.einsum("td,de->te", xf.astype(jnp.float32),
+                            p["router"].astype(jnp.float32))
+        scores = jax.nn.softmax(logits, axis=-1)
+        _, idx = jax.lax.top_k(
+            scores + p["router_bias"].astype(jnp.float32), m.top_k)
+        w = jnp.take_along_axis(scores, idx, axis=1) * m.routed_scale
+        local = idx - cfg.held_start
+        held = (local >= 0) & (local < n)
+        zero = idx >= m.n_experts
+        # (rows, held experts): the weight of each, 0 where not chosen
+        comb = jnp.einsum("tk,tkn->tn", jnp.where(held, w, 0.0),
+                          jax.nn.one_hot(jnp.where(held, local, 0), n))
+    with jax.named_scope("moe.experts"):
+        h = jax.nn.silu(jnp.einsum("td,ndf->ntf", xf, p["wg"])) * \
+            jnp.einsum("td,ndf->ntf", xf, p["wu"])
+        h = h * comb.T[:, :, None].astype(h.dtype)
+        out = jnp.einsum("ntf,nfd->td", h, p["wd"],
+                         preferred_element_type=jnp.float32)
+    with jax.named_scope("moe.zero"):
+        out = out + jnp.sum(jnp.where(zero, w, 0.0), axis=1)[:, None] * \
+            xf.astype(jnp.float32)
+    rows = jnp.stack([jnp.sum(held, axis=1), jnp.sum(zero, axis=1)], -1)
+    rows = jnp.sum(rows.reshape(B, S, 2), axis=1).astype(jnp.int32)
+    return out.astype(x.dtype).reshape(B, S, d), rows

@@ -96,6 +96,10 @@ class ServeEngine:
         #: test asserts tick executions == steps exactly
         self.step_count = 0
         self.prefill_count = 0
+        #: live rows' expert choices (held experts, identity experts) in
+        #: decode steps, summed over steps and layers; zeros where no
+        #: layer routes
+        self.moe_rows = np.zeros(2, np.int64)
 
     # ----------------------------------------------------------- prefill
     def clip_max_new(self, prompt_len: int, max_new: int) -> int:
@@ -122,6 +126,7 @@ class ServeEngine:
         self.pos[:] = 0
         self.step_count = 0
         self.prefill_count = 0
+        self.moe_rows[:] = 0
 
     # ------------------------------------------------------------ decode
     def attach(self, slot: int, prompt_len: int, first_token: int,
@@ -143,15 +148,22 @@ class ServeEngine:
         Traced, it records ``engine.step`` with two children:
         ``engine.step.launch`` (the uploads of tokens and positions and the
         jitted call returning) and ``engine.step.read`` (the token read,
-        which waits for the device)."""
-        with edat.span("engine.step"):
+        which waits for the device).  A model with held experts also
+        returns each row's expert choices, read with the tokens; the live
+        rows' are added to ``moe_rows`` and set on ``engine.step`` as
+        ``moe_held_rows`` and ``moe_zero_rows``."""
+        with edat.span("engine.step") as sp:
             with edat.span("engine.step.launch"):
-                nxt, self.caches = self._decode(self.params, self.caches,
-                                                jnp.asarray(self.tokens),
-                                                jnp.asarray(self.pos))
+                nxt, self.caches, rows = self._decode(
+                    self.params, self.caches, jnp.asarray(self.tokens),
+                    jnp.asarray(self.pos))
             self.step_count += 1
             with edat.span("engine.step.read"):
-                out = np.asarray(nxt)
+                out, rows = jax.device_get((nxt, rows))
+            if rows is not None:
+                rows = rows[list(live)].sum(axis=0)
+                self.moe_rows += rows
+                sp.set(moe_held_rows=int(rows[0]), moe_zero_rows=int(rows[1]))
             for i in live:
                 self.tokens[i, 0] = out[i, 0]
                 self.pos[i, 0] += 1

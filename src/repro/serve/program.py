@@ -317,6 +317,9 @@ class ServeProgram:
             "served": self.served,
             "steps": eng.step_count if eng else 0,
             "prefills": eng.prefill_count if eng else 0,
+            # decode steps' expert choices on held / identity experts
+            "moe_held_rows": int(eng.moe_rows[0]) if eng else 0,
+            "moe_zero_rows": int(eng.moe_rows[1]) if eng else 0,
             "tick_execs": self.tick_execs,
             "slots_leaked": sum(1 for s in self.live if s is not None),
             "queue_left": len(self.queue),

@@ -91,13 +91,18 @@ def make_prefill_step(model, *, max_len: Optional[int] = None) -> Callable:
 
 
 def make_serve_step(model) -> Callable:
-    """serve_step(params, caches, tokens, pos) -> (next_tokens, caches).
+    """serve_step(params, caches, tokens, pos) -> (next_tokens, caches,
+    rows).
 
-    One decode step for the whole batch: greedy argmax next token."""
+    One decode step for the whole batch: greedy argmax next token.
+    ``rows``: each row's expert choices that fell on held and on identity
+    experts, int32 ``(batch, 2)``, or None where no layer routes to held
+    experts (``TransformerLM.decode_counted``)."""
 
     def serve_step(params, caches, tokens, pos):
-        logits, caches = model.decode_step(params, caches, tokens, pos)
+        logits, caches, rows = model.decode_counted(params, caches, tokens,
+                                                    pos)
         nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
-        return nxt, caches
+        return nxt, caches, rows
 
     return serve_step

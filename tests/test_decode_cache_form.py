@@ -1,6 +1,7 @@
 """The compiled form of the decode step: caches that decode writes one
-entry per row of (GQA global and sliding-window K/V/pos, MLA latents) ride
-in the layer scan's carry and are written in place, so the while body
+entry per row of (GQA global and sliding-window K/V/pos, MLA latents, the
+two latents of a LongCat-Flash layer) ride in the layer scan's carry and
+are written in place, so the while body
 holds no copy, scatter or dynamic-update-slice of one layer's cache slice,
 and the whole step copies each stacked leaf at most once, at entry (the
 caller's cache is not donated).  Caches rewritten whole every step (SSD
@@ -39,6 +40,10 @@ def _cfg(kind):
             n_layers=4, pattern=("mla",), remat="full",
             mla=MLACfg(q_lora=64, kv_lora=32, rope_dim=16, nope_dim=32,
                        v_dim=32))
+    if kind == "longcat":
+        # two MLA latent caches per layer, both carried and written in place
+        return reduce_cfg(ARCHS["longcat-flash-chat"].cfg).replace(
+            n_layers=4, remat="full")
     if kind == "ssd":
         return reduce_cfg(ARCHS["mamba2-370m"].cfg).replace(
             n_layers=4, remat="full")
@@ -116,7 +121,7 @@ def _hlo_dtype(name):
     return {"float32": "f32", "bfloat16": "bf16", "int32": "s32"}[name]
 
 
-@pytest.mark.parametrize("kind", ["attn+local", "mla"])
+@pytest.mark.parametrize("kind", ["attn+local", "mla", "longcat"])
 def test_written_caches_are_carried_and_written_in_place(kind):
     model = build_model(_cfg(kind))
     (comps, entry), caches = compiled_step(model)

@@ -50,7 +50,7 @@ def xs_ys_decode_step(model, params, caches, tokens, pos):
             pslices, cslices = slices
             out = []
             for ui, desc in enumerate(unit):
-                x, nc, a = layer_apply(pslices[f"u{ui}"], x, cfg=cfg,
+                x, nc, a, _ = layer_apply(pslices[f"u{ui}"], x, cfg=cfg,
                                        desc=desc, positions=pos,
                                        cache=cslices[ui])
                 out.append(nc)

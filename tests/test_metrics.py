@@ -168,11 +168,12 @@ def _traced(main, ranks=1, **kw):
 def test_spans_nest_under_the_running_task():
     def main(ctx):
         def task(c, e):
-            with edat.span("outer", k=1):
+            with edat.span("outer", k=1) as sp:
                 with edat.span("inner"):
                     pass
                 with edat.span("sibling"):
                     pass
+                sp.set(n=2)     # attrs known only once the work is done
         ctx.submit(task, name="work")
 
     spans, _ = _traced(main)
@@ -180,7 +181,7 @@ def test_spans_nest_under_the_running_task():
     assert set(by) == {"edat.task", "outer", "inner", "sibling"}
     task, outer = by["edat.task"], by["outer"]
     assert task[5]["task"] == "work" and task[4] == 0
-    assert outer[4] == task[3] and outer[5] == {"k": 1}
+    assert outer[4] == task[3] and outer[5] == {"k": 1, "n": 2}
     assert by["inner"][4] == outer[3] and by["sibling"][4] == outer[3]
     for rec in (outer, by["inner"], by["sibling"]):
         parent = {r[3]: r for r in spans}[rec[4]]
@@ -223,8 +224,9 @@ def test_span_without_tracing_is_the_shared_noop():
     def main(ctx):
         def task(c, e):
             seen.append(edat.span("x", a=1))
-            with seen[-1]:
+            with seen[-1] as sp:
                 c.lock("L")
+                sp.set(b=2)
         ctx.submit(task)
 
     with edat.Session(1) as s:
