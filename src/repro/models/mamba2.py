@@ -162,7 +162,11 @@ def mamba2_apply(p, x, *, cfg: ModelCfg,
                 y = ssd_reference(xs, dt, p["a_log"], b, c, chunk=s.chunk)
         new_cache = None
     elif T == 1:
-        # single-token decode: O(1) state update (the SSM selling point)
+        # single-token decode: O(1) state update (the SSM selling point).
+        # The state is (B,H,P,N), d_state minor, the order the chip stores
+        # the stack in (see mamba2_cache_spec): the fused update reads each
+        # layer's slice from the stack and writes the new one into the
+        # output stack in place, with no padded copy of the slice.
         xp = jnp.concatenate([cache["conv"], xbc], axis=1)
         conv_out = sum(xp[:, i] * p["conv_w"][i]
                        for i in range(s.d_conv)) + p["conv_b"]
@@ -176,10 +180,10 @@ def mamba2_apply(p, x, *, cfg: ModelCfg,
         A = -jnp.exp(p["a_log"].astype(jnp.float32))
         dt1 = dt[:, 0]                                   # (B,H)
         da = jnp.exp(dt1 * A)[:, :, None, None]
-        upd = (dt1[:, :, None, None] * bh[:, :, :, None]
-               * xs.astype(jnp.float32)[:, :, None, :])
-        state = cache["state"] * da + upd                # (B,H,N,P)
-        y = jnp.einsum("bhn,bhnp->bhp", ch.astype(jnp.float32), state)
+        dtx = dt1[:, :, None] * xs.astype(jnp.float32)   # (B,H,P)
+        state = cache["state"] * da + dtx[..., None] * bh[:, :, None, :]
+        y = jnp.sum(ch.astype(jnp.float32)[:, :, None, :] * state,
+                    axis=-1)                             # (B,H,P)
         y = y[:, None]                                   # (B,1,H,P)
         xs = xs[:, None]
         new_cache = {"conv": xp[:, 1:], "state": state}
@@ -198,13 +202,16 @@ def mamba2_apply(p, x, *, cfg: ModelCfg,
             c_p = jnp.pad(c, ((0, 0), (0, pad), (0, 0), (0, 0)))
         else:
             xs_p, dt_p, b_p, c_p = xs, dt, b, c
+        # the cache holds the state (B,H,P,N); ssd_reference's is (B,H,N,P)
         y, final_state = ssd_reference(
             xs_p, dt_p, p["a_log"], b_p, c_p, chunk=s.chunk,
-            init_state=cache["state"], return_final_state=True)
+            init_state=jnp.swapaxes(cache["state"], -1, -2),
+            return_final_state=True)
         y = y[:, :T]
         conv_tail = jnp.concatenate([cache["conv"], xbc_raw],
                                     axis=1)[:, -(s.d_conv - 1):]
-        new_cache = {"conv": conv_tail, "state": final_state}
+        new_cache = {"conv": conv_tail,
+                     "state": jnp.swapaxes(final_state, -1, -2)}
 
     y = y + p["d_skip"].astype(jnp.float32)[:, None] * xs.astype(jnp.float32)
     y = y.reshape(B, T, d_in).astype(x.dtype)
@@ -214,11 +221,15 @@ def mamba2_apply(p, x, *, cfg: ModelCfg,
 
 
 def mamba2_cache_spec(cfg: ModelCfg, batch: int) -> Dict[str, P]:
+    """Conv tail and float32 SSM state per slot.  The state is
+    ``(batch, heads, head_dim, d_state)``, d_state minor: on a TPU a 64-wide
+    head_dim as the minor axis pads to 128 lanes, and decode would relay
+    each layer's state slice out to that padded form and back."""
     s, d_in, nh, d_xbc = _dims(cfg)
     return {
         "conv": P((batch, s.d_conv - 1, d_xbc), ("batch", "dconv", "rec"),
                   "zeros"),
-        "state": P((batch, nh, s.d_state, s.head_dim),
-                   ("batch", "ssm_heads", "state", None), "zeros",
+        "state": P((batch, nh, s.head_dim, s.d_state),
+                   ("batch", "ssm_heads", None, "state"), "zeros",
                    dtype=jnp.float32),
     }

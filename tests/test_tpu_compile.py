@@ -95,24 +95,47 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _compiled_serve_step(arch, slots, topo, one_chip):
+    """``jit(make_serve_step(model))`` for ``arch`` at its widths, 4 layers,
+    ``slots`` x 1024, compiled for one described v5e chip."""
+    def sds(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    model = build_model(serving_cfg(
+        ARCHS[arch].cfg.replace(n_layers=4), 1024))
+    params = sds(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    caches = sds(model.abstract_cache(slots, 1024))
+    tok = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
+    with jax.default_device(topo.devices[0]):
+        return jax.jit(make_serve_step(model)).lower(
+            params, caches, tok, tok).compile()
+
+
 def test_decode_step_keeps_the_stored_cache_layout(topo, one_chip,
                                                    no_persistent_cache):
     """StableLM-2 widths, 4 layers, 16 slots x 1024: the chip stores the
     stacked K/V position-minor (64-wide heads, unpadded).  The layer loop
     carries them in that layout, so the only whole-stack copies are the
     two at entry, and no layer's K/V slice is copied (relayout) at all."""
-    def sds(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=one_chip), tree)
-
-    model = build_model(serving_cfg(
-        ARCHS["stablelm-1.6b"].cfg.replace(n_layers=4), 1024))
-    params = sds(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    caches = sds(model.abstract_cache(16, 1024))
-    tok = jax.ShapeDtypeStruct((16, 1), jnp.int32, sharding=one_chip)
-    with jax.default_device(topo.devices[0]):
-        text = jax.jit(make_serve_step(model)).lower(
-            params, caches, tok, tok).compile().as_text()
+    text = _compiled_serve_step("stablelm-1.6b", 16, topo,
+                                one_chip).as_text()
     copies = re.findall(r"= bf16\[([\d,]+)\]\S* copy\(", text)
     assert copies.count("4,16,1024,32,64") == 2, copies
     assert not {"1,16,1024,32,64", "16,1024,32,64"} & set(copies), copies
+
+
+def test_ssd_decode_updates_the_state_slice_in_place(topo, one_chip,
+                                                     no_persistent_cache):
+    """Mamba2-370m widths, 4 layers, 32 slots x 1024: the float32 state is
+    held d_state-minor, the order the chip stores the stack in (128 wide,
+    unpadded).  Each layer's update reads its slice from the stack and
+    writes the new slice into the output stack, so no slice is copied
+    (relaid out to a padded head_dim-minor form and back), and the
+    temporaries hold no slice."""
+    compiled = _compiled_serve_step("mamba2-370m", 32, topo, one_chip)
+    copies = re.findall(r"= f32\[([\d,]+)\]\S* copy\(",
+                        compiled.as_text())
+    assert not [c for c in copies if c.startswith(("1,32,32,", "32,32,"))], \
+        copies
+    assert compiled.memory_analysis().temp_size_in_bytes < 8e6
