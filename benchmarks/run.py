@@ -1,4 +1,4 @@
-"""Benchmark orchestrator: one benchmark per paper table/figure + roofline.
+"""Benchmark orchestrator: one benchmark per paper table/figure.
 
   python -m benchmarks.run            # small defaults (CI-sized)
   python -m benchmarks.run --full     # paper-shaped sweeps (slow on 1 core)
@@ -9,7 +9,6 @@ processes of their own, since a chip belongs to one process at a time.
 from __future__ import annotations
 
 import argparse
-import json
 import os
 import sys
 
@@ -74,17 +73,6 @@ def main(argv=None):
                        insights=True,
                        out=os.path.join(args.outdir, "serve_load.json"))
 
-    print("== roofline (from dry-run artifacts, if present) ==")
-    from benchmarks import roofline
-    for mesh in ("pod16x16", "pod2x16x16"):
-        d = os.path.join("experiments", "dryrun", mesh)
-        if os.path.isdir(d) and os.listdir(d):
-            print(f"-- mesh {mesh} --")
-            roofline.run(d, os.path.join("experiments",
-                                         f"roofline_{mesh}.json"))
-        else:
-            print(f"-- mesh {mesh}: no dry-run artifacts; run "
-                  f"`python -m repro.launch.dryrun --all` first --")
     print("benchmarks complete; json in", args.outdir)
     return 0
 

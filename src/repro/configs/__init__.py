@@ -2,7 +2,6 @@
 from typing import Dict
 
 from .base import ArchSpec, reduce_cfg
-from .shapes import SHAPES, ShapeCfg
 
 from . import (deepseek_v3_671b, gemma2_2b, gemma3_1b, granite_moe_1b,
                internvl2_76b, longcat_flash_chat, mamba2_370m,
@@ -14,4 +13,4 @@ _MODULES = [internvl2_76b, deepseek_v3_671b, granite_moe_1b, whisper_tiny,
 
 ARCHS: Dict[str, ArchSpec] = {m.SPEC.name: m.SPEC for m in _MODULES}
 
-__all__ = ["ARCHS", "ArchSpec", "SHAPES", "ShapeCfg", "reduce_cfg"]
+__all__ = ["ARCHS", "ArchSpec", "reduce_cfg"]

@@ -1,8 +1,8 @@
-"""ArchSpec: a full-size config + shape applicability + reduced smoke config."""
+"""ArchSpec: a full-size config + reduced smoke config."""
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, FrozenSet, Optional
+from typing import Optional
 
 from repro.models.config import MLACfg, ModelCfg, MoECfg, RGLRUCfg, SSMCfg
 
@@ -10,10 +10,6 @@ from repro.models.config import MLACfg, ModelCfg, MoECfg, RGLRUCfg, SSMCfg
 @dataclasses.dataclass(frozen=True)
 class ArchSpec:
     cfg: ModelCfg
-    # shapes skipped for this arch (documented in DESIGN.md §Arch-applicability)
-    skip_shapes: FrozenSet[str] = frozenset()
-    # per-shape gradient-accumulation (memory control for train cells)
-    microbatches: Optional[Dict[str, int]] = None
     published_params: Optional[float] = None   # total param count to assert
     param_tolerance: float = 0.08
 

@@ -21,7 +21,5 @@ CFG = ModelCfg(
 
 SPEC = ArchSpec(
     cfg=CFG,
-    skip_shapes=frozenset({"long_500k"}),   # MLA is full attention
-    microbatches={"train_4k": 32},
     published_params=671e9,
 )

@@ -18,7 +18,5 @@ CFG = ModelCfg(
 
 SPEC = ArchSpec(
     cfg=CFG,
-    skip_shapes=frozenset(),  # half the layers are windowed
-    microbatches={"train_4k": 4},
     published_params=2.6e9,
 )

@@ -19,7 +19,5 @@ CFG = ModelCfg(
 
 SPEC = ArchSpec(
     cfg=CFG,
-    skip_shapes=frozenset(),  # local-dominant; global layers O(seq)/token
-    microbatches={"train_4k": 4},
     published_params=1.0e9,
 )

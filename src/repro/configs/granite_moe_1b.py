@@ -18,7 +18,5 @@ CFG = ModelCfg(
 
 SPEC = ArchSpec(
     cfg=CFG,
-    skip_shapes=frozenset({"long_500k"}),   # full attention
-    microbatches={"train_4k": 4},
     published_params=1.3e9,
 )

@@ -18,7 +18,5 @@ CFG = ModelCfg(
 
 SPEC = ArchSpec(
     cfg=CFG,
-    skip_shapes=frozenset({"long_500k"}),   # pure full attention
-    microbatches={"train_4k": 16},
     published_params=70.6e9,                # LM backbone (ViT stubbed)
 )

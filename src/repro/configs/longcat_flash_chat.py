@@ -25,6 +25,5 @@ CFG = ModelCfg(
 
 SPEC = ArchSpec(
     cfg=CFG,
-    skip_shapes=frozenset({"long_500k"}),   # MLA is full attention
     published_params=560e9,
 )

@@ -17,7 +17,5 @@ CFG = ModelCfg(
 
 SPEC = ArchSpec(
     cfg=CFG,
-    skip_shapes=frozenset(),                # constant-state: runs long_500k
-    microbatches={"train_4k": 4},
     published_params=370e6,
 )

@@ -18,8 +18,6 @@ CFG = ModelCfg(
 
 SPEC = ArchSpec(
     cfg=CFG,
-    skip_shapes=frozenset(),                # recurrent + windowed: long OK
-    microbatches={"train_4k": 8},
     published_params=9e9,
     param_tolerance=0.35,  # dense (not block-diagonal) RG-LRU gates
 )

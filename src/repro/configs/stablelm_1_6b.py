@@ -15,7 +15,5 @@ CFG = ModelCfg(
 
 SPEC = ArchSpec(
     cfg=CFG,
-    skip_shapes=frozenset({"long_500k"}),   # pure full attention
-    microbatches={"train_4k": 4},
     published_params=1.64e9,
 )

@@ -1,7 +1,7 @@
 """StarCoder2-15B [arXiv:2402.19173; hf].
 
 40L d_model=6144 48H (GQA kv=4) d_ff=24576 vocab=49152; sliding window
-4096 (sub-quadratic: long_500k runs), RoPE, biases.
+4096, RoPE, biases.
 """
 from repro.models.config import ModelCfg
 from .base import ArchSpec
@@ -16,7 +16,5 @@ CFG = ModelCfg(
 
 SPEC = ArchSpec(
     cfg=CFG,
-    skip_shapes=frozenset(),                # windowed attention
-    microbatches={"train_4k": 8},
     published_params=15e9,
 )

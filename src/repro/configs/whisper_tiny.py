@@ -19,8 +19,6 @@ CFG = ModelCfg(
 
 SPEC = ArchSpec(
     cfg=CFG,
-    skip_shapes=frozenset({"long_500k"}),   # full attention both stacks
-    microbatches={"train_4k": 1},
     published_params=39e6,
     param_tolerance=0.35,  # conv frontend + learned positions stubbed out
 )

@@ -191,7 +191,7 @@ def analyze(stats: Mapping[str, Any], *,
 
 
 def render(findings: List[Finding]) -> str:
-    """Markdown rendering of a findings list (``benchmarks/report.py``)."""
+    """Markdown rendering of a findings list."""
     if not findings:
         return "_no findings — the counters look healthy_\n"
     return "".join(f"- **{f.rule}** — {f.message}\n" for f in findings)

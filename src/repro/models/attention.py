@@ -1,9 +1,9 @@
 """Attention mixers: GQA (global / sliding-window) and DeepSeek MLA.
 
 Training/prefill paths can dispatch to the Pallas flash kernel
-(``cfg.attn_impl == 'pallas'``); decode and CPU dry-run use the XLA
-reference path.  Caches carry an explicit per-slot ``pos`` array so global
-caches and ring-buffered sliding-window caches share one masking rule.
+(``cfg.attn_impl == 'pallas'``); decode uses the XLA reference path.
+Caches carry an explicit per-slot ``pos`` array so global caches and
+ring-buffered sliding-window caches share one masking rule.
 """
 from __future__ import annotations
 

@@ -2,8 +2,8 @@
 
 Parameters are plain pytrees (nested dicts) of ``jnp`` arrays.  Every leaf is
 declared once as a :class:`P` spec carrying its *logical axes* (MaxText-style)
-— ``sharding/rules.py`` maps logical axes onto mesh axes, and the dry-run
-derives ``ShapeDtypeStruct`` + ``NamedSharding`` trees from the same specs
+— ``sharding/rules.py`` maps logical axes onto mesh axes, and
+:func:`abstract_tree` derives ``ShapeDtypeStruct`` trees from the same specs
 without ever materialising weights.
 """
 from __future__ import annotations
@@ -68,7 +68,7 @@ def init_tree(specs, key: jax.Array, dtype) -> Any:
 
 
 def abstract_tree(specs, dtype) -> Any:
-    """ShapeDtypeStruct tree (no allocation) — used by the dry-run."""
+    """ShapeDtypeStruct tree (no allocation)."""
     return jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype or dtype), specs,
         is_leaf=lambda x: isinstance(x, P))

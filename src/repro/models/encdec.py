@@ -169,13 +169,6 @@ class EncDecLM:
         return stack_spec(attn.gqa_cache_spec(cfg, "attn", batch, max_len),
                           cfg.n_layers)
 
-    def abstract_cache(self, batch: int, max_len: int):
-        return jax.tree.map(
-            lambda s: jax.ShapeDtypeStruct(s.shape,
-                                           s.dtype or jnp.dtype(self.cfg.dtype)),
-            self.cache_specs(batch, max_len),
-            is_leaf=lambda x: isinstance(x, P))
-
     def init_cache(self, batch: int, max_len: int):
         c = jax.tree.map(
             lambda s: jnp.zeros(s.shape, s.dtype or jnp.dtype(self.cfg.dtype)),

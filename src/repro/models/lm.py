@@ -529,12 +529,6 @@ class TransformerLM:
             return u
         return [[fix(u) for u in seg] for seg in cache]
 
-    def abstract_cache(self, batch: int, max_len: int):
-        return jax.tree.map(
-            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype or _dt(self.cfg)),
-            self.cache_specs(batch, max_len),
-            is_leaf=lambda x: isinstance(x, P))
-
     def prefill(self, params, tokens, caches, *, patch_embeds=None):
         """Forward over a prompt, writing caches; returns (last_logits, caches)."""
         cfg = self.cfg

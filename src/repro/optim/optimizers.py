@@ -3,11 +3,10 @@
 All states are pytrees mirroring the parameter tree, so the logical-axis
 sharding rules apply unchanged (ZeRO-1/3: under fsdp rules, moments shard
 over 'data' exactly like the parameters).  ``abstract_state`` builds the
-ShapeDtypeStruct tree for the dry-run without allocating anything.
+ShapeDtypeStruct tree without allocating anything.
 
 int8 moments (``adamw8``) store per-tensor absmax-scaled int8 m/v — a 7x
-optimizer-memory cut vs fp32 Adam, which is what lets the 671B config fit
-the assigned pod (see EXPERIMENTS.md §Dry-run).
+optimizer-memory cut vs fp32 Adam.
 """
 from __future__ import annotations
 

@@ -1,3 +1,3 @@
-from .rules import (DEFAULT_RULES, fsdp_rules, serve_rules, sp_rules,
-                    resolve, tree_shardings, with_updates)
+from .rules import (DEFAULT_RULES, fsdp_rules, serve_rules, resolve,
+                    tree_shardings, with_updates)
 from .ctx import use_sharding, constrain, current

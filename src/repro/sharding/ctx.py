@@ -1,8 +1,8 @@
 """Ambient sharding context: activation constraints inside model code.
 
 Model code calls ``constrain(x, logical_axes)`` at key points; outside a
-mesh context (unit tests on one CPU device) it is the identity, inside the
-dry-run / launcher it becomes ``with_sharding_constraint`` with the active
+mesh context (unit tests on one CPU device) it is the identity, inside
+``use_sharding`` it becomes ``with_sharding_constraint`` with the active
 rule set.  This keeps the model pure while letting experiments flip rules.
 """
 from __future__ import annotations

@@ -4,7 +4,7 @@ Every parameter/activation dimension carries a *logical* axis name; a rule
 set maps logical names to mesh axes.  ``resolve`` drops a mapping whenever
 the dimension is not divisible by the mesh-axis extent (e.g. 4 query heads
 cannot shard over a 16-way 'model' axis — gemma3-1b), so every config lowers
-on every mesh, and the roofline table shows the cost of the fallback.
+on every mesh.
 """
 from __future__ import annotations
 
@@ -52,22 +52,6 @@ def with_updates(base: Dict[str, AxisVal], **kw) -> Dict[str, AxisVal]:
 # (ZeRO-3 via GSPMD: XLA all-gathers per layer inside the step).
 def fsdp_rules(base: Dict[str, AxisVal] = None) -> Dict[str, AxisVal]:
     return with_updates(base or DEFAULT_RULES, embed="data")
-
-
-# Sequence-parallel rules for long-context cells: shard the KV-cache length
-# (and activation seq) over 'data'; batch stays on 'pod' only.
-def sp_rules(base: Dict[str, AxisVal] = None) -> Dict[str, AxisVal]:
-    return with_updates(base or DEFAULT_RULES,
-                        batch=("pod",), seq="data", cache="data")
-
-
-# Megatron-style sequence parallelism for training: the residual stream is
-# sharded over 'model' on the sequence axis between blocks, so each
-# TP partial-sum all-reduce becomes reduce-scatter(+all-gather before the
-# next projection) — ~2x less wire than AR of the full activation (and the
-# f32-partial AR that XLA emits becomes RS(f32)+AG(bf16): ~2.7x).
-def tp_sp_rules(base: Dict[str, AxisVal] = None) -> Dict[str, AxisVal]:
-    return with_updates(base or fsdp_rules(), residual_seq="model")
 
 
 # Serving rules: experts spread over BOTH axes (256 experts / 256 chips),

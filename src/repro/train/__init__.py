@@ -1,1 +1,1 @@
-from .step import make_train_step, make_prefill_step, make_serve_step
+from .step import make_prefill_step, make_serve_step

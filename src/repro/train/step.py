@@ -1,69 +1,10 @@
-"""Step builders: train_step (remat + microbatch accumulation), prefill_step,
-serve_step.  These are the functions the launcher jits/lowers; the dry-run
-lowers exactly these with abstract inputs."""
+"""Step builders: prefill_step and serve_step, the two programs the
+serving engine jits."""
 from __future__ import annotations
 
-import functools
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Callable, Optional
 
-import jax
 import jax.numpy as jnp
-
-from repro.optim import Optimizer
-from repro.sharding.ctx import constrain
-
-
-def make_train_step(model, opt: Optimizer, *, microbatches: int = 1,
-                    acc_dtype=jnp.float32) -> Callable:
-    """Returns train_step(params, opt_state, batch, step) ->
-    (params, opt_state, metrics).
-
-    microbatches > 1: gradient accumulation via lax.scan over batch slices
-    (peak activation memory divides by the accumulation factor).  Keep the
-    per-microbatch batch >= the data-parallel mesh extent or the whole
-    model replicates across 'data' (see EXPERIMENTS.md §Perf, deepseek).
-
-    acc_dtype: gradient-accumulator dtype.  bfloat16 halves both the
-    accumulator HBM traffic and the per-microbatch gradient reduction
-    bytes, at the cost of ~3 mantissa bits across the accumulation sum."""
-
-    def loss_fn(p, mb):
-        loss, metrics = model.loss(p, mb)
-        return loss, metrics
-
-    grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
-
-    def train_step(params, opt_state, batch, step):
-        if microbatches == 1:
-            (loss, metrics), grads = grad_fn(params, batch)
-        else:
-            def split(x):
-                b = x.shape[0]
-                assert b % microbatches == 0, (b, microbatches)
-                return x.reshape((microbatches, b // microbatches)
-                                 + x.shape[1:])
-            mbs = jax.tree.map(split, batch)
-
-            def acc(carry, mb):
-                g_acc, l_acc = carry
-                (loss, _m), g = grad_fn(params, mb)
-                g_acc = jax.tree.map(
-                    lambda a, b_: a + b_.astype(acc_dtype), g_acc, g)
-                return (g_acc, l_acc + loss), None
-
-            g0 = jax.tree.map(
-                lambda p: jnp.zeros(p.shape, acc_dtype), params)
-            (g_sum, l_sum), _ = jax.lax.scan(acc, (g0, 0.0), mbs)
-            grads = jax.tree.map(lambda g: (g / microbatches), g_sum)
-            loss = l_sum / microbatches
-            metrics = {"ce": loss}
-        new_params, new_state, opt_metrics = opt.update(
-            grads, opt_state, params, step)
-        out = {"loss": loss, **{k: v for k, v in metrics.items()},
-               **opt_metrics}
-        return new_params, new_state, out
-
-    return train_step
 
 
 def make_prefill_step(model, *, max_len: Optional[int] = None) -> Callable:

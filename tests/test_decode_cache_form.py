@@ -8,20 +8,25 @@ caller's cache is not donated).  Caches rewritten whole every step (SSD
 state) keep the scan's xs->ys form: no entry copy of the stacked state.
 
 Compiled on the CPU from ``jax.jit(make_serve_step(model))``, the program
-``ServeEngine`` runs, and read from the optimised HLO text.
+``ServeEngine`` runs, and read from the optimised HLO text.  Under a mesh
+the form differs, the results do not: prefill and decode inside
+``use_sharding`` match the run without one.
 """
+import contextlib
 import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.configs import ARCHS, reduce_cfg
 from repro.models import build_model
 from repro.models.config import MLACfg
 from repro.models.lm import STACK_WRITTEN
-from repro.train import make_serve_step
+from repro.sharding import serve_rules, use_sharding
+from repro.train import make_prefill_step, make_serve_step
 
 B, MAX_LEN = 3, 16
 
@@ -87,7 +92,7 @@ def _reachable(comps, roots):
 
 def compiled_step(model, step=None):
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    caches = model.abstract_cache(B, MAX_LEN)
+    caches = jax.eval_shape(lambda: model.init_cache(B, MAX_LEN))
     tok = jax.ShapeDtypeStruct((B, 1), jnp.int32)
     text = jax.jit(step or make_serve_step(model)).lower(
         params, caches, tok, tok).compile().as_text()
@@ -160,9 +165,6 @@ def test_rewritten_state_keeps_its_form():
 def test_under_a_mesh_written_caches_keep_xs_ys():
     """With a mesh active the batch may be split across devices, where a
     row's write into the stack would gather it: the scan keeps xs->ys."""
-    from jax.sharding import AxisType
-    from repro.sharding.ctx import use_sharding
-    from repro.sharding.rules import serve_rules
     model = build_model(_cfg("attn+local"))
     mesh = jax.make_mesh((1, 1), ("data", "model"),
                          axis_types=(AxisType.Auto,) * 2)
@@ -179,3 +181,53 @@ def test_under_a_mesh_written_caches_keep_xs_ys():
     scattered = {(dt, dims) for c in inside for dt, dims, op, _ in comps[c]
                  if op == "scatter"}
     assert scattered == per_layer
+
+
+@pytest.mark.parametrize("arch", ["gemma3-1b", "granite-moe-1b-a400m",
+                                  "whisper-tiny", "longcat-flash-chat"])
+def test_tokens_unchanged_under_sharding_rules(arch):
+    """Prefill and three decode steps give the same tokens, logits and
+    caches inside ``use_sharding`` (serving rules, the host's one-device
+    mesh) as without a mesh: the rules place arrays, never change them."""
+    cfg = reduce_cfg(ARCHS[arch].cfg)
+    if arch == "longcat-flash-chat":
+        # a held share: 4 of the 8 routed experts, the rest routed away
+        cfg = cfg.replace(held_experts=4, held_start=2)
+    model = build_model(cfg)
+    key = jax.random.PRNGKey(0)
+    params = model.init(key)
+    prompt = 8
+    batch = {"tokens": jax.random.randint(key, (B, prompt), 0, cfg.vocab)}
+    if cfg.frontend == "audio":
+        batch["frame_embeds"] = jax.random.normal(
+            key, (B, prompt, cfg.d_model), jnp.float32)
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
+
+    def run(sharded):
+        def jit(fn):
+            def wrapped(*args):
+                with (use_sharding(mesh, serve_rules()) if sharded
+                      else contextlib.nullcontext()):
+                    return fn(*args)
+            return jax.jit(wrapped)
+
+        logits, caches = jit(make_prefill_step(model, max_len=MAX_LEN))(
+            params, batch)
+        tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
+        toks, step = [tok], jit(make_serve_step(model))
+        for i in range(3):
+            pos = jnp.full((B, 1), prompt + i, jnp.int32)
+            tok, caches, _ = step(params, caches, tok, pos)
+            toks.append(tok)
+        return logits, np.concatenate(toks, axis=1), caches
+
+    lg, toks, caches = run(sharded=False)
+    lg_s, toks_s, caches_s = run(sharded=True)
+    np.testing.assert_array_equal(toks_s, toks)
+    np.testing.assert_allclose(np.asarray(lg_s), np.asarray(lg),
+                               rtol=1e-5, atol=1e-5)
+    assert jax.tree.structure(caches_s) == jax.tree.structure(caches)
+    for a, b in zip(jax.tree.leaves(caches_s), jax.tree.leaves(caches)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)

@@ -105,7 +105,7 @@ def _compiled_serve_step(arch, slots, topo, one_chip):
     model = build_model(serving_cfg(
         ARCHS[arch].cfg.replace(n_layers=4), 1024))
     params = sds(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    caches = sds(model.abstract_cache(slots, 1024))
+    caches = sds(jax.eval_shape(lambda: model.init_cache(slots, 1024)))
     tok = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
     with jax.default_device(topo.devices[0]):
         return jax.jit(make_serve_step(model)).lower(
